@@ -19,6 +19,7 @@ def run(n: int = 300, quick: bool = False):
     n = 80 if quick else n
     jm = make_resnet_model("fig2", scale=16, img=64, batches=(1,))
     jm.warmup(reps=2)
+    jm.load()          # EXEC needs the weights on the device
     lats = [jm.run(1) for _ in range(n)]
     s = latency_summary(lats)
     rows = [(q, v * 1e3) for q, v in latency_quantiles(lats)]

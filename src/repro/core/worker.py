@@ -8,8 +8,11 @@ queue best-effort work, which is what stops stragglers from cascading.
 
 Backends supply durations:
   * SimBackend — profile tables + configurable noise/spikes (C3), virtual time
-  * callable backends (serving/engine.py) — actually execute JAX programs and
-    return measured wall time (RealClock)
+  * JaxBackend (serving/engine.py) — actually executes JAX programs on the
+    device `gpu_id` names and returns measured wall time (RealClock)
+
+Both answer `load_duration(model, gpu_id)`, `unload(model, gpu_id)` and
+`exec_duration(model, action)`.
 """
 from __future__ import annotations
 
@@ -33,7 +36,6 @@ class ModelDef:
     exec_latency: Dict[Tuple[str, int], float]   # (action_type, batch) -> s
     input_bytes: int = 602_112                   # paper Table 1 default
     output_bytes: int = 4_096
-    runner: Optional[Callable] = None            # real execution hook
 
     def pages(self, page_bytes: int = PAGE_BYTES) -> int:
         return PageCache.pages_for(self.weights_bytes, page_bytes)
@@ -65,9 +67,12 @@ class SimBackend:
             d *= self.spike_scale
         return d
 
-    def load_duration(self, model: ModelDef) -> float:
+    def load_duration(self, model: ModelDef, gpu_id: int) -> float:
         return self._jitter(self.load_fixed
                             + model.weights_bytes / self.host_to_dev_bw)
+
+    def unload(self, model: ModelDef, gpu_id: int) -> None:
+        pass  # simulated weights occupy nothing but their pages
 
     def exec_duration(self, model: ModelDef, action: Action) -> float:
         key = (action.type.value, action.batch_size)
@@ -125,18 +130,21 @@ class Executor:
                 self.worker.emit_result(action, status, now, now, 0.0)
                 continue
             self.busy = True
-            end = loop.now() + (0.0 if self.worker.backend.realtime
-                                else duration)
+            if self.worker.backend.realtime:
+                # the action has already run: it ended now, whatever else
+                # the loop runs before _done, and its duration is all the
+                # time it held this executor
+                end = loop.now()
+                duration = end - now
+            else:
+                end = now + duration
             self.busy_until = end
             self.total_busy += duration
 
-            def _done(a=action, t0=now, d=duration):
+            def _done(a=action, t0=now, t1=end, d=duration):
                 self.busy = False
                 self.worker.finish(a)
-                self.worker.emit_result(a, ResultStatus.SUCCESS, t0,
-                                        self.worker.loop.now()
-                                        if self.worker.backend.realtime
-                                        else t0 + d, d)
+                self.worker.emit_result(a, ResultStatus.SUCCESS, t0, t1, d)
                 self._poll()
 
             loop.schedule(end, _done)
@@ -196,9 +204,11 @@ class Worker:
                 return ResultStatus.SUCCESS, 1e-5
             if not pc.alloc(action.model_id, model.pages(pc.page_bytes)):
                 return ResultStatus.ERROR_NO_PAGES, 0.0
-            return ResultStatus.SUCCESS, self.backend.load_duration(model)
+            return ResultStatus.SUCCESS, self.backend.load_duration(
+                model, action.gpu_id)
         if action.type == ActionType.UNLOAD:
-            pc.free(action.model_id)
+            if pc.free(action.model_id):
+                self.backend.unload(model, action.gpu_id)
             return ResultStatus.SUCCESS, 1e-5
         # EXEC family
         if not pc.contains(action.model_id):

@@ -8,21 +8,17 @@ import; nothing else in the codebase does.
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType landed in jax 0.5; older releases imply Auto axes
-    from jax.sharding import AxisType
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}
-except ImportError:
-    _AXIS_KW = lambda n: {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests use small ones, e.g. (2, 4))."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_AXIS_KW(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -20,8 +20,12 @@ SimBackend or the real JAX engine runners) and bridges it over a Channel:
   single-process runs).
 
 `python -m repro.runtime.worker --controller HOST:PORT ...` runs the
-daemon: a RealClock EventLoop + RealtimePump, a SimBackend-backed Worker
-over the Table-1 demo model set, and a TCP channel to the controller.
+daemon: a RealClock EventLoop + RealtimePump, a Worker, and a TCP channel
+to the controller. `--backend sim` (the default) simulates the Table-1
+demo model set; `--backend jax` serves `--n-models` seeded ResNet-50
+copies on the first `--gpus` JAX devices, compiling every batch bucket and
+measuring the INFER and LOAD profiles it sends in HELLO before it
+connects.
 """
 from __future__ import annotations
 
@@ -29,6 +33,7 @@ import argparse
 import dataclasses
 import signal
 import sys
+import time
 from typing import List, Optional
 
 from repro.core.clock import EventLoop, RealClock, RealtimePump
@@ -237,6 +242,33 @@ def demo_models(n_models: int):
             for i in range(n_models)}
 
 
+def build_jax_worker(worker_id: str, loop: EventLoop, devices, *,
+                     n_models: int, scale: int, img: int, seed: int):
+    """A Worker serving `resnet_fleet` copies on `devices` (gpu_id g is
+    devices[g]) with every bucket compiled and the profiles measured on the
+    device; returns (worker, profiles). Each page cache covers what the
+    device's allocator reports."""
+    from repro.serving.engine import (JaxBackend, device_memory_bytes,
+                                      resnet_fleet, seed_engines,
+                                      use_compile_cache)
+    use_compile_cache()
+    engines = resnet_fleet(n_models, scale, img, seed=seed)
+    first = next(iter(engines.values()))
+    t0 = time.perf_counter()
+    first.compile(devices)     # the copies share these executables
+    first.warm(devices)
+    print(f"[worker {worker_id}] {len(first.programs.compile_s)} programs "
+          f"compiled and run in {time.perf_counter() - t0:.1f}s for "
+          f"{len(devices)} x {devices[0].device_kind}",
+          file=sys.stderr, flush=True)
+    profiles = seed_engines(engines)
+    models = {mid: e.modeldef() for mid, e in engines.items()}
+    memory = min(device_memory_bytes(d) for d in devices)
+    worker = Worker(worker_id, loop, JaxBackend(engines, devices), models,
+                    n_gpus=len(devices), device_memory_bytes=memory)
+    return worker, profiles
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         prog="python -m repro.runtime.worker",
@@ -244,10 +276,21 @@ def main(argv=None) -> int:
                     "over TCP and executes actions on the local backend.")
     p.add_argument("--controller", required=True, metavar="HOST:PORT")
     p.add_argument("--worker-id", required=True)
+    p.add_argument("--backend", choices=("sim", "jax"), default="sim",
+                   help="sim: Table-1 latencies on a virtual device; jax: "
+                        "ResNet-50 copies executed on JAX devices")
     p.add_argument("--n-models", type=int, default=4,
-                   help="size of the shared Table-1 demo model set")
-    p.add_argument("--gpus", type=int, default=1)
-    p.add_argument("--memory-gb", type=float, default=32.0)
+                   help="number of models m0..m{n-1} (the shared demo "
+                        "model set under sim, ResNet-50 copies under jax)")
+    p.add_argument("--reduced", action="store_true",
+                   help="jax: ResNet-50 at 1/16 width on 64x64 inputs "
+                        "(CPU tests) instead of the published widths")
+    p.add_argument("--gpus", type=int, default=1,
+                   help="devices this worker drives (jax: the first N of "
+                        "jax.devices())")
+    p.add_argument("--memory-gb", type=float, default=32.0,
+                   help="sim only: simulated device memory (the jax "
+                        "backend reads it from the device)")
     p.add_argument("--noise", type=float, default=0.0003)
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--duration", type=float, default=None,
@@ -256,7 +299,9 @@ def main(argv=None) -> int:
     p.add_argument("--sync-interval", type=float, default=1.0)
     p.add_argument("--telemetry-interval", type=float, default=1.0)
     p.add_argument("--no-seed-profiles", action="store_true",
-                   help="do not send Table-1 seed profiles in HELLO")
+                   help="sim only: do not send Table-1 seed profiles in "
+                        "HELLO (the jax backend always sends measured "
+                        "ones)")
     p.add_argument("--telemetry-jsonl", default=None,
                    help="stream worker-side telemetry to this JSONL file")
     p.add_argument("--rotate-bytes", type=int, default=None,
@@ -264,26 +309,35 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
 
     host, _, port = args.controller.rpartition(":")
-    models = demo_models(args.n_models)
-
-    from repro.core.worker import SimBackend
     loop = EventLoop(RealClock())
     pump = RealtimePump(loop)
-    backend = SimBackend(noise=args.noise, seed=args.seed)
-    worker = Worker(args.worker_id, loop, backend, models,
-                    n_gpus=args.gpus,
-                    device_memory_bytes=args.memory_gb * 1e9)
+    if args.backend == "jax":
+        import jax
+        from repro.serving.engine import PUBLISHED, REDUCED
+        devices = jax.devices()
+        if args.gpus > len(devices):
+            p.error(f"--gpus {args.gpus}, but JAX sees {len(devices)} "
+                    f"device(s): {devices}")
+        worker, profiles = build_jax_worker(
+            args.worker_id, loop, devices[:args.gpus],
+            n_models=args.n_models, seed=args.seed,
+            **(REDUCED if args.reduced else PUBLISHED))
+    else:
+        from repro.core.worker import SimBackend
+        from repro.serving.simulator import seed_profiles
+        models = demo_models(args.n_models)
+        backend = SimBackend(noise=args.noise, seed=args.seed)
+        worker = Worker(args.worker_id, loop, backend, models,
+                        n_gpus=args.gpus,
+                        device_memory_bytes=args.memory_gb * 1e9)
+        profiles = None if args.no_seed_profiles \
+            else seed_profiles(models, backend.host_to_dev_bw)
 
     recorder = None
     if args.telemetry_jsonl:
         recorder = Recorder()
         recorder.stream_to(args.telemetry_jsonl,
                            rotate_bytes=args.rotate_bytes)
-
-    profiles = None
-    if not args.no_seed_profiles:
-        from repro.serving.simulator import seed_profiles
-        profiles = seed_profiles(models, backend.host_to_dev_bw)
 
     channel = tcp_connect(host, int(port), pump.post)
     hostside = WorkerHost(worker, channel, profiles=profiles,

@@ -2,10 +2,11 @@
 
 Mirrors the paper's model runtime (§5.1): each model is AOT-compiled per
 batch-size bucket (default 1,2,4,8,16 like Clockwork's TVM kernels), weights
-live in host memory and LOAD places them on device, EXEC runs exactly one
-XLA program at a time. Execution times are measured and fed back to the
-controller's profiler — on CPU they are noisier than a TPU (document the
-Fig-2 analogue caveat), but the machinery is identical.
+live in host memory and LOAD places them on one device, EXEC runs exactly
+one XLA program at a time on the device that holds the weights, UNLOAD
+frees them there. Copies of one architecture share their compiled
+programs. Execution times are measured and fed back to the controller's
+profiler.
 
 Profiles are persistent: `seed_from_store` / `seed_engines` load a
 ProfileStore written by the offline profiler CLI
@@ -14,46 +15,128 @@ warmup re-measurements (`warmup_count` stays 0).
 """
 from __future__ import annotations
 
+import os
 import time
+from pathlib import Path
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from repro.core.worker import ModelDef
 from repro.models import params as pspec
 from repro.models.resnet import resnet50_forward, resnet50_spec
 from repro.telemetry.profile_store import ProfileStore
 
+BUCKETS = (1, 2, 4, 8, 16)
+# ResNet-50 as the paper serves it (ImageNet: 1000 classes, 224x224 input)
+# and the 1/16-width, 64-pixel cut that CPU tests run
+PUBLISHED = {"scale": 1, "img": 224}
+REDUCED = {"scale": 16, "img": 64}
+# bf16 serving logits against a float32 forward of the same weights, by
+# relative L2 error (check_logits). ~50 layers each round to bf16 (unit
+# roundoff 2^-9); the XLA CPU backend lands at 0.3-0.7% for seeds 0-2 at
+# reduced widths. 2% leaves room for another backend's rounding while an
+# 8-bit float (roundoff 2^-4) would miss it.
+LOGITS_RTOL = 0.02
+CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> None:
+    """Persist compiled programs across processes. JAX reads
+    JAX_COMPILATION_CACHE_DIR itself when it is set; otherwise the cache
+    lives at one fixed path in the checkout, so later runs find it."""
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+
+
+def device_memory_bytes(device) -> int:
+    """Memory a worker's page cache may divide up on `device`: the
+    allocator's limit where the backend reports one. A CPU device's memory
+    is the host's."""
+    stats = device.memory_stats()
+    if stats and "bytes_limit" in stats:
+        return int(stats["bytes_limit"])
+    if device.platform == "cpu":
+        return os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES")
+    raise RuntimeError(f"{device} reports no memory limit")
+
+
+def _default(device):
+    return device if device is not None else jax.devices()[0]
+
+
+class NotLoadedError(RuntimeError):
+    """EXEC on a device that does not hold the model's weights."""
+
+
+class Executables:
+    """One compiled executable per (device, batch bucket) of a forward
+    function. Copies of a model differ only in their weights, so they share
+    one of these instead of compiling once each."""
+
+    def __init__(self, forward: Callable):
+        self.forward = forward
+        self._exe: Dict[tuple, object] = {}
+        self.compile_s: Dict[tuple, float] = {}   # (device, bucket) -> s
+
+    def get(self, device, b: int, params, x):
+        """The executable for bucket `b` on `device`, compiled on first use
+        for arguments shaped like `params` and `x` (arrays or
+        ShapeDtypeStructs)."""
+        exe = self._exe.get((device, b))
+        if exe is None:
+            on_device = SingleDeviceSharding(device)
+
+            def spec(a):
+                return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                            sharding=on_device)
+
+            t0 = time.perf_counter()
+            exe = jax.jit(self.forward).lower(
+                jax.tree.map(spec, params), jax.tree.map(spec, x)).compile()
+            self.compile_s[(device, b)] = time.perf_counter() - t0
+            self._exe[(device, b)] = exe
+        return exe
+
 
 class JaxModel:
-    """One served model: params + per-batch-bucket jit'd callables."""
+    """One served model: host weights, their copies on devices, and the
+    (shared) per-bucket executables."""
 
     def __init__(self, model_id: str, forward: Callable, params,
-                 make_input: Callable[[int], dict], weights_bytes: int,
-                 batches: Tuple[int, ...] = (1, 2, 4, 8, 16)):
+                 make_input: Callable[[int], object], weights_bytes: int,
+                 batches: Tuple[int, ...] = BUCKETS,
+                 programs: Optional[Executables] = None):
         self.model_id = model_id
-        self.forward = forward
         self.host_params = jax.tree.map(np.asarray, params)
-        self.device_params = None
-        self.make_input = make_input
+        self.device_params: Dict[object, object] = {}   # device -> weights
+        self.make_input = make_input     # bucket -> host (numpy) input
+        self._inputs: Dict[int, object] = {}
         self.weights_bytes = weights_bytes
         self.batches = tuple(sorted(batches))
-        self._jitted = {b: jax.jit(forward) for b in self.batches}
+        self.programs = programs if programs is not None \
+            else Executables(forward)
         self._measured: Dict[Tuple[str, int], float] = {}
         self._load_s: Optional[float] = None
         self._fresh: set = set()     # keys measured in-process (not echoes)
         self.warmup_count = 0        # timed profiling measurements performed
 
-    def load(self) -> float:
+    def load(self, device=None) -> float:
+        device = _default(device)
         t0 = time.perf_counter()
-        self.device_params = jax.device_put(self.host_params)
-        jax.block_until_ready(self.device_params)
+        params = jax.device_put(self.host_params, device)
+        jax.block_until_ready(params)
+        self.device_params[device] = params
         return time.perf_counter() - t0
 
-    def unload(self):
-        self.device_params = None
+    def unload(self, device=None):
+        """Free this copy's buffers on `device` now, not whenever the
+        garbage collector runs, so device memory matches the page cache."""
+        for a in jax.tree.leaves(self.device_params.pop(_default(device),
+                                                        None)):
+            a.delete()
 
     def bucket(self, batch: int) -> int:
         for b in self.batches:
@@ -61,46 +144,87 @@ class JaxModel:
                 return b
         return self.batches[-1]
 
-    def run(self, batch: int) -> float:
+    def execute(self, b: int, x, device):
+        """Run bucket `b` on input `x` with the weights held on `device`;
+        returns the (not yet awaited) output. Never loads weights."""
+        params = self.device_params.get(device)
+        if params is None:
+            raise NotLoadedError(
+                f"{self.model_id} has no weights on {device}")
+        exe = self.programs.get(device, b, self.host_params, x)
+        return exe(params, jax.device_put(x, device))
+
+    def run(self, batch: int, device=None) -> float:
+        """One EXEC: copy a `batch`-bucket input to `device` and run the
+        program there. Returns its wall time, which is all the work the
+        action does, so the profile it feeds covers the whole action."""
+        device = _default(device)
         b = self.bucket(batch)
-        if self.device_params is None:
-            self.load()
-        x = self.make_input(b)
+        x = self._input(b)
         t0 = time.perf_counter()
-        out = self._jitted[b](self.device_params, x)
-        jax.block_until_ready(out)
+        jax.block_until_ready(self.execute(b, x, device))
         return time.perf_counter() - t0
 
-    def compile(self):
-        """AOT-compile every batch bucket without recording timings —
-        compilation is not warmup re-measurement (paper §5.1: kernels are
-        compiled ahead of time; profiles come from the ProfileStore)."""
-        if self.device_params is None:
-            self.load()
-        for b in self.batches:
-            x = self.make_input(b)
-            jax.block_until_ready(self._jitted[b](self.device_params, x))
+    def _input(self, b: int):
+        """The host input of bucket `b`: one seeded payload per bucket
+        stands in for the requests' own."""
+        x = self._inputs.get(b)
+        if x is None:
+            x = self._inputs[b] = self.make_input(b)
+        return x
+
+    def compile(self, devices=None):
+        """AOT-compile every batch bucket for each device, untimed and
+        without loading weights — compilation is not warmup re-measurement
+        (paper §5.1: kernels are compiled ahead of time; profiles come from
+        the ProfileStore)."""
+        for d in devices or [_default(None)]:
+            for b in self.batches:
+                self.programs.get(d, b, self.host_params, self._input(b))
+
+    def warm(self, devices) -> None:
+        """Run every bucket once on each device, so that no first-run cost
+        (loading the program onto the device, growing its allocator) lands
+        in a served EXEC. Leaves no weights behind."""
+        for d in devices:
+            self.load(d)
+            try:
+                for b in self.batches:
+                    self.run(b, d)
+            finally:
+                self.unload(d)
 
     # ------------------------------------------------------ profiling
-    def measure(self, reps: int = 3) -> Dict[Tuple[str, int], list]:
+    def measure(self, reps: int = 3, device=None
+                ) -> Dict[Tuple[str, int], list]:
         """Timed sweep over batch buckets; returns raw durations per
-        ("INFER", batch). The first rep per bucket (compile) is dropped."""
-        if self.device_params is None:
-            self.load()
-        out = {}
-        for b in self.batches:
-            durs = [self.run(b) for _ in range(reps + 1)][1:]
-            self.warmup_count += reps + 1
-            out[("INFER", b)] = durs
+        ("INFER", batch). The first rep per bucket (compile) is dropped.
+        Weights this loads for the sweep are unloaded after it."""
+        device = _default(device)
+        borrowed = device not in self.device_params
+        if borrowed:
+            self.load(device)
+        try:
+            out = {}
+            for b in self.batches:
+                durs = [self.run(b, device) for _ in range(reps + 1)][1:]
+                self.warmup_count += reps + 1
+                out[("INFER", b)] = durs
+        finally:
+            if borrowed:
+                self.unload(device)
         return out
 
-    def measure_load(self, reps: int = 2) -> List[float]:
-        """Timed host->device weight transfers (the LOAD profile)."""
+    def measure_load(self, reps: int = 2, device=None) -> List[float]:
+        """Timed host->device weight transfers (the LOAD profile); leaves
+        no weights on the device."""
+        device = _default(device)
         durs = []
         for _ in range(max(1, reps)):
-            self.unload()
-            durs.append(max(self.load(), 1e-5))
+            self.unload(device)
+            durs.append(max(self.load(device), 1e-5))
             self.warmup_count += 1
+        self.unload(device)
         self._load_s = float(np.median(durs))
         self._fresh.add(("LOAD", 1))
         return durs
@@ -159,24 +283,29 @@ class JaxModel:
         return ModelDef(model_id=self.model_id,
                         weights_bytes=self.weights_bytes,
                         exec_latency={("INFER", b): d for (_, b), d
-                                      in self._measured.items()},
-                        runner=self.run)
+                                      in self._measured.items()})
 
 
 class JaxBackend:
-    """Worker backend that actually executes (RealClock mode)."""
+    """Worker backend that actually executes (RealClock mode): `gpu_id` g
+    is `devices[g]`."""
 
     realtime = True
-    load_fixed = 1e-4
 
-    def __init__(self, models: Dict[str, JaxModel]):
+    def __init__(self, models: Dict[str, JaxModel], devices=None):
         self.models = models
+        self.devices = list(devices) if devices is not None \
+            else jax.devices()
 
-    def load_duration(self, model: ModelDef) -> float:
-        return max(self.models[model.model_id].load(), 1e-6)
+    def load_duration(self, model: ModelDef, gpu_id: int) -> float:
+        return self.models[model.model_id].load(self.devices[gpu_id])
+
+    def unload(self, model: ModelDef, gpu_id: int) -> None:
+        self.models[model.model_id].unload(self.devices[gpu_id])
 
     def exec_duration(self, model: ModelDef, action) -> float:
-        return max(self.models[model.model_id].run(action.batch_size), 1e-6)
+        return self.models[model.model_id].run(
+            action.batch_size, self.devices[action.gpu_id])
 
 
 def seed_engines(engines: Dict[str, JaxModel],
@@ -213,19 +342,65 @@ def update_store(engines: Dict[str, JaxModel], store: ProfileStore,
     return store
 
 
-def make_resnet_model(model_id: str, scale: int = 16, img: int = 64,
-                      batches=(1, 2, 4, 8, 16), seed: int = 0) -> JaxModel:
-    """Reduced ResNet-50 (the paper's evaluation model) runnable on CPU."""
-    spec = resnet50_spec(num_classes=256, scale=scale)
-    params = pspec.materialize(spec, jax.random.PRNGKey(seed))
+def make_resnet_model(model_id: str, scale: int = REDUCED["scale"],
+                      img: int = REDUCED["img"], batches=BUCKETS,
+                      seed: int = 0,
+                      programs: Optional[Executables] = None) -> JaxModel:
+    """ResNet-50 (the paper's evaluation model) with seeded weights:
+    published widths at `scale=1` on 224-pixel inputs, narrower for CPU
+    runs. Weights are made on the host CPU, where they live between
+    LOADs."""
+    spec = resnet50_spec(num_classes=1000, scale=scale)
+    with jax.default_device(jax.devices("cpu")[0]):
+        params = pspec.materialize(spec, jax.random.PRNGKey(seed))
     rng = np.random.default_rng(seed)
 
     def make_input(b):
-        return jnp.asarray(rng.standard_normal((b, img, img, 3)),
-                           jnp.float32)
+        return rng.standard_normal((b, img, img, 3), dtype=np.float32)
 
     return JaxModel(model_id, resnet50_forward, params, make_input,
-                    weights_bytes=pspec.param_bytes(spec), batches=batches)
+                    weights_bytes=pspec.param_bytes(spec), batches=batches,
+                    programs=programs)
+
+
+def resnet_fleet(n_models: int, scale: int, img: int,
+                 seed: int = 0) -> Dict[str, JaxModel]:
+    """`n_models` ResNet-50 copies m0..m{n-1}, each with its own seeded
+    weights, sharing one set of compiled programs."""
+    programs = Executables(resnet50_forward)
+    return {f"m{i}": make_resnet_model(f"m{i}", scale, img, seed=seed + i,
+                                       programs=programs)
+            for i in range(n_models)}
+
+
+def resnet_fleet_defs(n_models: int, scale: int) -> Dict[str, ModelDef]:
+    """The controller's view of `resnet_fleet`: the same ids and weight
+    bytes, computed from ParamSpec shapes without a JAX backend."""
+    nbytes = pspec.param_bytes(resnet50_spec(num_classes=1000, scale=scale))
+    return {f"m{i}": ModelDef(f"m{i}", weights_bytes=nbytes, exec_latency={})
+            for i in range(n_models)}
+
+
+def check_logits(model: JaxModel, device) -> dict:
+    """Bucket-1 logits of `model` served on `device` for its seeded input,
+    against a float32 forward of the same weights on the host CPU, by
+    relative L2 error. A bf16 result that agrees to LOGITS_RTOL passes;
+    computing below bf16 (or a wrong program) does not."""
+    x = model.make_input(1)
+    model.load(device)
+    try:
+        out = np.asarray(model.execute(1, x, device), np.float32)
+    finally:
+        model.unload(device)
+    ref_params = jax.tree.map(lambda a: np.asarray(a, np.float32),
+                              model.host_params)
+    with jax.default_device(jax.devices("cpu")[0]), \
+            jax.default_matmul_precision("highest"):
+        ref = np.asarray(jax.jit(model.programs.forward)(ref_params, x))
+    rel = float(np.linalg.norm(out - ref) / np.linalg.norm(ref))
+    return {"rel_l2": rel, "rtol": LOGITS_RTOL, "shape": list(out.shape),
+            "finite": bool(np.isfinite(out).all()),
+            "ok": bool(np.isfinite(out).all() and rel <= LOGITS_RTOL)}
 
 
 def make_lm_decode_model(model_id: str, arch: str = "qwen2-0.5b",
@@ -248,8 +423,7 @@ def make_lm_decode_model(model_id: str, arch: str = "qwen2-0.5b",
         return logits
 
     def make_input(b):
-        return (jnp.zeros((b, 1), jnp.int32),
-                jnp.asarray(ctx // 2, jnp.int32))
+        return (np.zeros((b, 1), np.int32), np.asarray(ctx // 2, np.int32))
 
     return JaxModel(model_id, forward, params, make_input,
                     weights_bytes=pspec.param_bytes(bundle.spec()),
