@@ -1,8 +1,7 @@
 """The worker's JAX backend on CPU at reduced width: LOAD places a copy's
 weights on the device its gpu_id names, EXEC runs there and never loads,
 UNLOAD frees the buffers, copies share compiled programs, and the daemon
-serves through the TCP runtime. Also the chip smoke script's refusals
-when there is no chip."""
+serves through the TCP runtime."""
 import json
 import os
 import subprocess
@@ -255,20 +254,3 @@ def test_daemon_and_demo_refuse_impossible_device_requests(tmp_path):
         env=_env(tmp_path), capture_output=True, text=True, timeout=120)
     assert demo.returncode == 2
     assert "one daemon per host" in demo.stderr
-
-
-def test_chip_smoke_fails_without_a_chip_or_a_checkout(tmp_path):
-    smoke = os.path.join(REPO, "chip_smoke.py")
-    proc = subprocess.run([sys.executable, smoke], env=_env(tmp_path),
-                          capture_output=True, text=True, timeout=120)
-    assert proc.returncode != 0
-    last = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert last["ok"] is False and "device" not in last
-    alone = tmp_path / "alone"
-    alone.mkdir()
-    (alone / "chip_smoke.py").write_text(open(smoke).read())
-    proc = subprocess.run([sys.executable, str(alone / "chip_smoke.py")],
-                          env=_env(tmp_path), capture_output=True,
-                          text=True, timeout=120, cwd=alone)
-    assert proc.returncode != 0
-    assert '"ok"' not in proc.stdout
