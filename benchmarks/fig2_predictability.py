@@ -8,6 +8,7 @@ the distribution is still orders tighter than the concurrent-execution tail
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 from benchmarks.common import report_line, write_csv
@@ -17,10 +18,11 @@ from repro.telemetry.reports import latency_quantiles, latency_summary
 
 def run(n: int = 300, quick: bool = False):
     n = 80 if quick else n
+    dev = jax.devices()[0]
     jm = make_resnet_model("fig2", scale=16, img=64, batches=(1,))
-    jm.warmup(reps=2)
-    jm.load()          # EXEC needs the weights on the device
-    lats = [jm.run(1) for _ in range(n)]
+    jm.warmup(dev, reps=2)
+    jm.load(dev)       # EXEC needs the weights on the device
+    lats = [jm.run(1, dev) for _ in range(n)]
     s = latency_summary(lats)
     rows = [(q, v * 1e3) for q, v in latency_quantiles(lats)]
     write_csv("fig2_predictability", rows, ["quantile", "latency_ms"])

@@ -7,6 +7,8 @@ architectures (written by benchmarks/roofline.py from dry-run artifacts).
 """
 from __future__ import annotations
 
+import jax
+
 from benchmarks.common import report_line, write_csv
 from repro.serving.engine import make_lm_decode_model, make_resnet_model
 
@@ -26,7 +28,7 @@ def run(quick: bool = False):
         ]
     for name, mk in specs:
         jm = mk()
-        prof = jm.seed_profiles()
+        prof = jm.seed_profiles(jax.devices()[0])
         load_ms = prof[("LOAD", name, 1)] * 1e3
         b_ms = {b: prof[("INFER", name, b)] * 1e3
                 for b in jm.batches}

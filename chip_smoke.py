@@ -18,7 +18,10 @@ each of 8 ResNet-50 copies for 3 s under the paper's 100 ms SLO. No copy
 is preloaded, so the controller must LOAD before it can EXEC.
 
 This process never touches JAX, since a chip belongs to one process at a
-time. The last line of stdout is one JSON object: `{"ok": true, "device":
+time. Its children run with `JAX_PLATFORMS` naming `tpu` (and `cpu`), so
+a chip that fails to start is an error, never a quiet CPU run, and phase
+B fails unless every device the daemon names in its HELLO is a TPU. The
+last line of stdout is one JSON object: `{"ok": true, "device":
 {...}}` when every check passed on a TPU, otherwise `{"ok": false, ...}`
 with a non-zero exit. `--cpu-rehearsal` runs the same phases at 1/16
 width on whatever devices JAX finds, to rehearse without a chip; its "ok"
@@ -124,7 +127,11 @@ def serve(gpus: int, traffic: list, args, failures: list) -> dict:
     print(f"{tag} learned INFER profile (s) per bucket "
           f"{out['infer_profile_s']}", flush=True)
     print(f"{tag} busy seconds per lane {out['busy_s']}", flush=True)
+    print(f"{tag} daemon devices {out['devices']}", flush=True)
+    platforms = {d["platform"] for ds in out["devices"].values() for d in ds}
     checks = {
+        f"daemon served on {sorted(platforms)}":
+            platforms != {"tpu"} and not args.cpu_rehearsal,
         "goodput is 0": c["goodput"] == 0 or out["goodput"] == 0,
         "a response was late": c["timeout"] > 0 or out["timeout"] > 0,
         "no LOAD ran": out["loads"] == 0,
@@ -154,10 +161,12 @@ def main(argv=None) -> int:
     sys.path[:0] = [str(REPO / "src"), str(REPO / "examples")]
     os.environ["PYTHONPATH"] = os.pathsep.join(
         filter(None, [str(REPO / "src"), os.environ.get("PYTHONPATH")]))
-    platforms = os.environ.get("JAX_PLATFORMS")
+    # Every child names its platforms, so a TPU that fails to start raises
+    # instead of leaving JAX on the CPU. Phase A's float32 reference and
+    # the daemon's weight generation run on the host CPU beside the chip.
+    platforms = os.environ.get("JAX_PLATFORMS") or (
+        "" if args.cpu_rehearsal else "tpu")
     if platforms and "cpu" not in platforms.split(","):
-        # phase A's float32 reference and the daemon's weight generation
-        # run on the host CPU beside the chip
         os.environ["JAX_PLATFORMS"] = platforms + ",cpu"
     if args.child:
         return child(args)

@@ -14,8 +14,10 @@ import sys
 
 sys.path.insert(0, "src")
 
+import jax
+
 from repro.core.actions import Request
-from repro.core.clock import EventLoop, RealClock
+from repro.core.clock import EventLoop, RealClock, RealtimePump
 from repro.core.controller import Controller
 from repro.core.scheduler import ClockworkScheduler
 from repro.core.worker import Worker
@@ -30,6 +32,7 @@ STORE_PATH = "experiments/profiles.json"
 
 def main():
     loop = EventLoop(RealClock())
+    pump = RealtimePump(loop)
     print("[quickstart] compiling model batch buckets (AOT, like the "
           "paper's per-batch-size TVM kernels)...")
     engines = {
@@ -42,13 +45,14 @@ def main():
     if store is not None:
         print(f"[quickstart] seeding profiles from {STORE_PATH} "
               "(skipping warmup re-measurement)")
-    profiles = seed_engines(engines, store)
+    dev = jax.devices()[0]
+    profiles = seed_engines(engines, dev, store)
     for e in engines.values():
         if e.warmup_count == 0:   # store-seeded: warmup didn't compile it
-            e.compile()   # AOT, untimed — keeps compiles off the hot path
+            e.compile([dev])   # AOT, untimed — keeps compiles off hot path
     models = {k: v.modeldef() for k, v in engines.items()}
-    backend = JaxBackend(engines)
-    worker = Worker("w0", loop, backend, models, n_gpus=1)
+    backend = JaxBackend(engines, [dev])
+    worker = Worker("w0", loop, backend, models, n_gpus=1, post=pump.post)
     controller = Controller(loop, models, ClockworkScheduler(),
                             action_delay=1e-4)
     controller.add_worker(worker, profiles)
@@ -61,8 +65,8 @@ def main():
     for i in range(30):
         controller.on_request(Request(model_id=list(models)[i % 2],
                                       arrival=loop.now(), slo=slo))
-        loop.run_until(loop.now() + 0.01)
-    loop.run_until(loop.now() + 5.0)
+        pump.run(timeout=0.01)
+    pump.run(timeout=5.0)
 
     ok = [r for r in done if r.status == "ok"]
     lat = [r.completion - r.arrival for r in ok]

@@ -46,6 +46,9 @@ from repro.telemetry.reports import quantile
 # covers a daemon's cold compile and profiling of every batch bucket; a
 # daemon that exits earlier ends the wait at once
 REGISTER_TIMEOUT_S = 600.0
+# covers a daemon's exit after GOODBYE, accelerator runtime teardown
+# included
+EXIT_TIMEOUT_S = 60.0
 
 
 def parse_args(argv=None):
@@ -131,6 +134,7 @@ def serve(args) -> dict:
                  timeout=REGISTER_TIMEOUT_S)
         if len(controller.workers) < args.workers:
             raise RuntimeError("workers never registered")
+        devices = {wid: stub.devices for wid, stub in server.stubs.items()}
         print(f"[controller] {len(controller.workers)} workers registered",
               flush=True)
 
@@ -171,13 +175,14 @@ def serve(args) -> dict:
         summary = controller.summary()
         net = {wid: round(m.net_delay * 1e6)
                for wid, m in controller.workers.items()}
-        # what the scheduler now plans with, per batch bucket (median
-        # over the models)
-        est = controller.profiler.estimate
-        profile = {b: quantile([e for mid in models
-                                if (e := est("INFER", mid, b)) is not None],
-                               0.5)
-                   for b in (1, 2, 4, 8, 16)}
+        # what the scheduler now plans with, per batch bucket the models
+        # were profiled at (median over the models)
+        prof = controller.profiler
+        buckets = sorted({b for mid in models
+                          for b in prof.known_batches("INFER", mid)})
+        profile = {b: quantile([e for mid in models if (e := prof.estimate(
+                       "INFER", mid, b)) is not None], 0.5)
+                   for b in buckets}
     finally:
         if lg is not None and lg.poll() is None:
             lg.kill()              # never orphan the loadgen tree
@@ -192,7 +197,7 @@ def serve(args) -> dict:
         rcs = []
         for pr in procs:
             try:
-                rcs.append(pr.wait(timeout=10.0))
+                rcs.append(pr.wait(timeout=EXIT_TIMEOUT_S))
             except subprocess.TimeoutExpired:
                 pr.kill()
                 rcs.append(-9)
@@ -204,7 +209,7 @@ def serve(args) -> dict:
     out = {"sent": sent, **summary,
            "loads": sum(r.action_type is ActionType.LOAD for r in done),
            "execs": sum(r.action_type in EXEC_TYPES for r in done),
-           "infer_profile_s": profile, "busy_s": busy,
+           "infer_profile_s": profile, "busy_s": busy, "devices": devices,
            "net_delay_us": net, "worker_returncodes": rcs,
            "worker_gauges": worker_gauges}
     if client_out is not None:

@@ -11,15 +11,24 @@ Backends supply durations:
   * JaxBackend (serving/engine.py) — actually executes JAX programs on the
     device `gpu_id` names and returns measured wall time (RealClock)
 
-Both answer `load_duration(model, gpu_id)`, `unload(model, gpu_id)` and
-`exec_duration(model, action)`.
+Both answer `load_duration(model, gpu_id)`, `unload(model, gpu_id)`,
+`exec_duration(model, action)` and `describe(gpu_id)` (the device's
+platform and kind, sent in HELLO).
+
+A realtime backend's actions take wall time, so a Worker runs each of its
+executors on a thread of its own and hands every end back through `post`,
+a thread-safe way onto its loop's thread (`RealtimePump.post`). A
+worker's lanes then overlap as the controller plans them: LOAD beside
+EXEC, and each device beside the others.
 """
 from __future__ import annotations
 
 import dataclasses
 import heapq
 import itertools
+import queue
 import random
+import threading
 from typing import Callable, Dict, Optional, Tuple
 
 from repro.core.actions import (EXEC_TYPES, Action, ActionType, Result,
@@ -39,6 +48,15 @@ class ModelDef:
 
     def pages(self, page_bytes: int = PAGE_BYTES) -> int:
         return PageCache.pages_for(self.weights_bytes, page_bytes)
+
+
+class NotLoadedError(RuntimeError):
+    """A backend was asked to EXEC a model its device holds no weights of
+    (for example while the model's LOAD is still running)."""
+
+
+def _instant() -> float:
+    return 1e-5
 
 
 class SimBackend:
@@ -71,6 +89,9 @@ class SimBackend:
         return self._jitter(self.load_fixed
                             + model.weights_bytes / self.host_to_dev_bw)
 
+    def describe(self, gpu_id: int) -> dict:
+        return {"platform": "sim", "kind": "simulated"}
+
     def unload(self, model: ModelDef, gpu_id: int) -> None:
         pass  # simulated weights occupy nothing but their pages
 
@@ -100,8 +121,8 @@ class Executor:
         self.q = []                      # heap: (earliest, seq, action)
         self._seq = itertools.count()
         self.busy = False
-        self.busy_until = 0.0
         self.total_busy = 0.0            # utilization telemetry
+        self.lane = _Lane(self) if worker.backend.realtime else None
 
     def submit(self, action: Action):
         heapq.heappush(self.q, (action.earliest, next(self._seq), action))
@@ -125,20 +146,16 @@ class Executor:
                 self.worker.emit_result(action, ResultStatus.REJECTED_LATE,
                                         now, now, 0.0)
                 continue
-            status, duration = self.worker.perform(action)
+            status, work = self.worker.perform(action)
             if status is not ResultStatus.SUCCESS:
                 self.worker.emit_result(action, status, now, now, 0.0)
                 continue
             self.busy = True
-            if self.worker.backend.realtime:
-                # the action has already run: it ended now, whatever else
-                # the loop runs before _done, and its duration is all the
-                # time it held this executor
-                end = loop.now()
-                duration = end - now
-            else:
-                end = now + duration
-            self.busy_until = end
+            if self.lane is not None:
+                self.lane.jobs.put((action, now, work))   # ends in _end
+                return
+            duration = work()
+            end = now + duration
             self.total_busy += duration
 
             def _done(a=action, t0=now, t1=end, d=duration):
@@ -150,6 +167,57 @@ class Executor:
             loop.schedule(end, _done)
             return
 
+    def _end(self, action: Action, t0: float, t1: float,
+             err: Optional[ResultStatus]):
+        """A realtime action ran from t0 to t1 (called on the loop's
+        thread); its duration is all the time it held this executor."""
+        self.busy = False
+        if err is None:
+            self.total_busy += t1 - t0
+            self.worker.finish(action)
+            self.worker.emit_result(action, ResultStatus.SUCCESS, t0, t1,
+                                    t1 - t0)
+        else:
+            self.worker.emit_result(action, err, t0, t1, 0.0)
+        self._poll()
+
+
+class _Lane(threading.Thread):
+    """One executor's thread: runs its realtime actions in order and hands
+    each end back to the loop's thread through the worker's `post`. Any
+    failure but NotLoadedError is raised again on the loop's thread, so no
+    action fails quietly."""
+
+    def __init__(self, ex: Executor):
+        super().__init__(name=f"{ex.worker.worker_id}/gpu{ex.gpu_id}/"
+                              f"{ex.name}", daemon=True)
+        self.ex = ex
+        self.jobs: "queue.SimpleQueue" = queue.SimpleQueue()
+        self.start()
+
+    def run(self):
+        clock, post = self.ex.worker.loop.clock, self.ex.worker.post
+        while True:
+            job = self.jobs.get()
+            if job is None:
+                return
+            action, t0, work = job
+            err = None
+            try:
+                work()
+            except NotLoadedError:
+                err = ResultStatus.ERROR_NOT_LOADED
+            except BaseException as e:
+                post(lambda e=e: _raise(e))
+                return
+            t1 = clock.now()    # RealClock reads time.monotonic: any thread
+            post(lambda a=action, t0=t0, t1=t1, e=err:
+                 self.ex._end(a, t0, t1, e))
+
+
+def _raise(e: BaseException):
+    raise e
+
 
 class Worker:
     """One worker process managing `n_gpus` accelerator slices."""
@@ -158,11 +226,16 @@ class Worker:
                  backend: SimBackend, models: Dict[str, ModelDef],
                  n_gpus: int = 1, device_memory_bytes: float = 32e9,
                  reserved_bytes: float = 1e9,
-                 result_delay: float = 0.0005):
+                 result_delay: float = 0.0005,
+                 post: Optional[Callable[[Callable[[], None]], None]] = None):
         self.worker_id = worker_id
         self.loop = loop
         self.backend = backend
         self.models = models
+        if backend.realtime and post is None:
+            raise ValueError("a realtime backend needs `post` onto the "
+                             "loop's thread (RealtimePump.post)")
+        self.post = post        # thread-safe: run a callable on loop's thread
         self.alive = True
         self.result_delay = result_delay
         self.on_result: Optional[Callable[[Result], None]] = None
@@ -192,29 +265,47 @@ class Worker:
         """Crash: drop all queued work, stop emitting results."""
         self.alive = False
 
+    def close(self):
+        """Stop the lane threads once their running actions end, so no
+        device work is in flight when the process tears its backend
+        down."""
+        lanes = [ex.lane for ex in self.execs.values() if ex.lane]
+        for lane in lanes:
+            lane.jobs.put(None)
+        for lane in lanes:
+            lane.join()
+
     # -------------------------------------------------- execution
     def perform(self, action: Action):
-        """Returns (status, duration). Called at action start time."""
+        """Books `action` in the page cache when it starts and returns
+        (status, work): `work()` does it on the backend and returns its
+        duration, and is None unless the status is SUCCESS."""
         pc = self.pagecaches[action.gpu_id]
         model = self.models.get(action.model_id)
         if model is None:
-            return ResultStatus.ERROR_NOT_LOADED, 0.0
+            return ResultStatus.ERROR_NOT_LOADED, None
+        g = action.gpu_id
         if action.type == ActionType.LOAD:
             if pc.contains(action.model_id):
-                return ResultStatus.SUCCESS, 1e-5
+                return ResultStatus.SUCCESS, _instant
             if not pc.alloc(action.model_id, model.pages(pc.page_bytes)):
-                return ResultStatus.ERROR_NO_PAGES, 0.0
-            return ResultStatus.SUCCESS, self.backend.load_duration(
-                model, action.gpu_id)
+                return ResultStatus.ERROR_NO_PAGES, None
+            return ResultStatus.SUCCESS, \
+                lambda: self.backend.load_duration(model, g)
         if action.type == ActionType.UNLOAD:
-            if pc.free(action.model_id):
-                self.backend.unload(model, action.gpu_id)
-            return ResultStatus.SUCCESS, 1e-5
+            if not pc.free(action.model_id):
+                return ResultStatus.SUCCESS, _instant
+
+            def unload():
+                self.backend.unload(model, g)
+                return _instant()
+            return ResultStatus.SUCCESS, unload
         # EXEC family
         if not pc.contains(action.model_id):
-            return ResultStatus.ERROR_NOT_LOADED, 0.0
+            return ResultStatus.ERROR_NOT_LOADED, None
         pc.touch(action.model_id)
-        return ResultStatus.SUCCESS, self.backend.exec_duration(model, action)
+        return ResultStatus.SUCCESS, \
+            lambda: self.backend.exec_duration(model, action)
 
     def finish(self, action: Action):
         pass  # hook (real backends release IO buffers here)
@@ -234,13 +325,15 @@ class Worker:
 
     # -------------------------------------------------- runtime descriptor
     def spec(self) -> dict:
-        """Wire-serializable descriptor of this worker (memory geometry) —
-        the payload a WorkerDaemon sends in its HELLO so the controller can
-        build an exact PageCache mirror without sharing the process."""
+        """Wire-serializable descriptor of this worker (memory geometry and
+        what each device is) — the payload a WorkerDaemon sends in its
+        HELLO so the controller can build an exact PageCache mirror without
+        sharing the process."""
         return {"worker_id": self.worker_id,
                 "gpus": [{"total_pages": pc.total_pages,
-                          "page_bytes": pc.page_bytes}
-                         for pc in self.pagecaches]}
+                          "page_bytes": pc.page_bytes,
+                          **self.backend.describe(g)}
+                         for g, pc in enumerate(self.pagecaches)]}
 
     # -------------------------------------------------- telemetry
     def utilization(self, horizon: float) -> Dict[str, float]:

@@ -64,6 +64,8 @@ class RemoteWorkerStub:
         self.worker_id = worker_id
         self.pagecaches = [_PageSpec(g["total_pages"], g["page_bytes"])
                            for g in gpu_specs]
+        self.devices = [{"platform": g["platform"], "kind": g["kind"]}
+                        for g in gpu_specs]
         self.server = server
         self.alive = True
         self.graceful = False           # set before an expected disconnect

@@ -218,9 +218,12 @@ def hello(worker_id: str, gpus: List[dict],
 
 def gpus_from_hello(msg: dict) -> List[dict]:
     """Validated pagecache geometry from a HELLO (ints or it's a
-    ProtocolError via `decode`)."""
+    ProtocolError via `decode`), with each device's platform and kind
+    where the worker named them."""
     return [{"total_pages": int(g["total_pages"]),
-             "page_bytes": int(g["page_bytes"])} for g in field(msg, "gpus")]
+             "page_bytes": int(g["page_bytes"]),
+             "platform": str(g.get("platform", "")),
+             "kind": str(g.get("kind", ""))} for g in field(msg, "gpus")]
 
 
 def profiles_from_hello(msg: dict) -> Optional[dict]:

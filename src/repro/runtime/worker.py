@@ -25,7 +25,8 @@ to the controller. `--backend sim` (the default) simulates the Table-1
 demo model set; `--backend jax` serves `--n-models` seeded ResNet-50
 copies on the first `--gpus` JAX devices, compiling every batch bucket and
 measuring the INFER and LOAD profiles it sends in HELLO before it
-connects.
+connects; each device's LOAD and EXEC lanes then run on threads of their
+own, so they overlap as the controller plans them.
 """
 from __future__ import annotations
 
@@ -242,12 +243,13 @@ def demo_models(n_models: int):
             for i in range(n_models)}
 
 
-def build_jax_worker(worker_id: str, loop: EventLoop, devices, *,
+def build_jax_worker(worker_id: str, loop: EventLoop, devices, post, *,
                      n_models: int, scale: int, img: int, seed: int):
     """A Worker serving `resnet_fleet` copies on `devices` (gpu_id g is
     devices[g]) with every bucket compiled and the profiles measured on the
     device; returns (worker, profiles). Each page cache covers what the
-    device's allocator reports."""
+    device's allocator reports. `post` (the pump's) lets each lane run on
+    its own thread."""
     from repro.serving.engine import (JaxBackend, device_memory_bytes,
                                       resnet_fleet, seed_engines,
                                       use_compile_cache)
@@ -255,17 +257,19 @@ def build_jax_worker(worker_id: str, loop: EventLoop, devices, *,
     engines = resnet_fleet(n_models, scale, img, seed=seed)
     first = next(iter(engines.values()))
     t0 = time.perf_counter()
-    first.compile(devices)     # the copies share these executables
+    for e in engines.values():
+        e.compile(devices)     # the copies share the first one's programs
     first.warm(devices)
     print(f"[worker {worker_id}] {len(first.programs.compile_s)} programs "
           f"compiled and run in {time.perf_counter() - t0:.1f}s for "
           f"{len(devices)} x {devices[0].device_kind}",
           file=sys.stderr, flush=True)
-    profiles = seed_engines(engines)
+    profiles = seed_engines(engines, devices[0])
     models = {mid: e.modeldef() for mid, e in engines.items()}
     memory = min(device_memory_bytes(d) for d in devices)
     worker = Worker(worker_id, loop, JaxBackend(engines, devices), models,
-                    n_gpus=len(devices), device_memory_bytes=memory)
+                    n_gpus=len(devices), device_memory_bytes=memory,
+                    post=post)
     return worker, profiles
 
 
@@ -319,7 +323,7 @@ def main(argv=None) -> int:
             p.error(f"--gpus {args.gpus}, but JAX sees {len(devices)} "
                     f"device(s): {devices}")
         worker, profiles = build_jax_worker(
-            args.worker_id, loop, devices[:args.gpus],
+            args.worker_id, loop, devices[:args.gpus], pump.post,
             n_models=args.n_models, seed=args.seed,
             **(REDUCED if args.reduced else PUBLISHED))
     else:
@@ -357,8 +361,11 @@ def main(argv=None) -> int:
         # duration elapsed: leave gracefully, give the ack a moment
         hostside.shutdown("duration elapsed")
         pump.run(until=lambda: hostside.closed, timeout=5.0)
+    worker.close()
     if recorder is not None:
         recorder.close_stream()
+    print(f"[worker {args.worker_id}] left the controller", file=sys.stderr,
+          flush=True)
     return 0
 
 

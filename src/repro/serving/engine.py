@@ -24,7 +24,7 @@ import jax
 import numpy as np
 from jax.sharding import SingleDeviceSharding
 
-from repro.core.worker import ModelDef
+from repro.core.worker import ModelDef, NotLoadedError
 from repro.models import params as pspec
 from repro.models.resnet import resnet50_forward, resnet50_spec
 from repro.telemetry.profile_store import ProfileStore
@@ -63,14 +63,6 @@ def device_memory_bytes(device) -> int:
     raise RuntimeError(f"{device} reports no memory limit")
 
 
-def _default(device):
-    return device if device is not None else jax.devices()[0]
-
-
-class NotLoadedError(RuntimeError):
-    """EXEC on a device that does not hold the model's weights."""
-
-
 class Executables:
     """One compiled executable per (device, batch bucket) of a forward
     function. Copies of a model differ only in their weights, so they share
@@ -103,7 +95,8 @@ class Executables:
 
 class JaxModel:
     """One served model: host weights, their copies on devices, and the
-    (shared) per-bucket executables."""
+    (shared) per-bucket executables. Every method that places or runs
+    something names its device."""
 
     def __init__(self, model_id: str, forward: Callable, params,
                  make_input: Callable[[int], object], weights_bytes: int,
@@ -123,19 +116,17 @@ class JaxModel:
         self._fresh: set = set()     # keys measured in-process (not echoes)
         self.warmup_count = 0        # timed profiling measurements performed
 
-    def load(self, device=None) -> float:
-        device = _default(device)
+    def load(self, device) -> float:
         t0 = time.perf_counter()
         params = jax.device_put(self.host_params, device)
         jax.block_until_ready(params)
         self.device_params[device] = params
         return time.perf_counter() - t0
 
-    def unload(self, device=None):
+    def unload(self, device):
         """Free this copy's buffers on `device` now, not whenever the
         garbage collector runs, so device memory matches the page cache."""
-        for a in jax.tree.leaves(self.device_params.pop(_default(device),
-                                                        None)):
+        for a in jax.tree.leaves(self.device_params.pop(device, None)):
             a.delete()
 
     def bucket(self, batch: int) -> int:
@@ -154,11 +145,10 @@ class JaxModel:
         exe = self.programs.get(device, b, self.host_params, x)
         return exe(params, jax.device_put(x, device))
 
-    def run(self, batch: int, device=None) -> float:
+    def run(self, batch: int, device) -> float:
         """One EXEC: copy a `batch`-bucket input to `device` and run the
         program there. Returns its wall time, which is all the work the
         action does, so the profile it feeds covers the whole action."""
-        device = _default(device)
         b = self.bucket(batch)
         x = self._input(b)
         t0 = time.perf_counter()
@@ -173,12 +163,12 @@ class JaxModel:
             x = self._inputs[b] = self.make_input(b)
         return x
 
-    def compile(self, devices=None):
-        """AOT-compile every batch bucket for each device, untimed and
-        without loading weights — compilation is not warmup re-measurement
-        (paper §5.1: kernels are compiled ahead of time; profiles come from
-        the ProfileStore)."""
-        for d in devices or [_default(None)]:
+    def compile(self, devices):
+        """AOT-compile every batch bucket for each device and make the
+        bucket's input, untimed and without loading weights — compilation
+        is not warmup re-measurement (paper §5.1: kernels are compiled
+        ahead of time; profiles come from the ProfileStore)."""
+        for d in devices:
             for b in self.batches:
                 self.programs.get(d, b, self.host_params, self._input(b))
 
@@ -195,12 +185,12 @@ class JaxModel:
                 self.unload(d)
 
     # ------------------------------------------------------ profiling
-    def measure(self, reps: int = 3, device=None
+    def measure(self, device, reps: int = 3
                 ) -> Dict[Tuple[str, int], list]:
-        """Timed sweep over batch buckets; returns raw durations per
-        ("INFER", batch). The first rep per bucket (compile) is dropped.
-        Weights this loads for the sweep are unloaded after it."""
-        device = _default(device)
+        """Timed sweep over batch buckets on `device`; returns raw
+        durations per ("INFER", batch). The first rep per bucket (compile)
+        is dropped. Weights this loads for the sweep are unloaded after
+        it."""
         borrowed = device not in self.device_params
         if borrowed:
             self.load(device)
@@ -215,10 +205,9 @@ class JaxModel:
                 self.unload(device)
         return out
 
-    def measure_load(self, reps: int = 2, device=None) -> List[float]:
+    def measure_load(self, device, reps: int = 2) -> List[float]:
         """Timed host->device weight transfers (the LOAD profile); leaves
-        no weights on the device."""
-        device = _default(device)
+        no weights on `device`."""
         durs = []
         for _ in range(max(1, reps)):
             self.unload(device)
@@ -229,8 +218,8 @@ class JaxModel:
         self._fresh.add(("LOAD", 1))
         return durs
 
-    def warmup(self, reps: int = 3):
-        for (t, b), durs in self.measure(reps=reps).items():
+    def warmup(self, device, reps: int = 3):
+        for (t, b), durs in self.measure(device, reps=reps).items():
             self._measured[(t, b)] = float(np.median(durs))
             self._fresh.add((t, b))
 
@@ -259,27 +248,32 @@ class JaxModel:
         self.apply_profile(entries)
         return True
 
-    def seed_profiles(self) -> dict:
+    def seed_profiles(self, device) -> dict:
+        """The INFER and LOAD profiles, measuring on `device` whatever no
+        store seeded."""
         if not self._measured:
-            self.warmup()
+            self.warmup(device)
+        if self._load_s is None:
+            self.measure_load(device, reps=1)
+        return self._profiles()
+
+    def _profiles(self) -> dict:
         out = {("INFER", self.model_id, b): d
                for (_, b), d in self._measured.items()}
-        if self._load_s is None:
-            self.measure_load(reps=1)
-        out[("LOAD", self.model_id, 1)] = self._load_s
+        if self._load_s is not None:
+            out[("LOAD", self.model_id, 1)] = self._load_s
         return out
 
     def fresh_profiles(self) -> dict:
-        """Like seed_profiles(), restricted to values measured in this
-        process — store-seeded echoes are excluded, so folding these back
-        into a ProfileStore can never recycle its own estimates."""
-        return {(t, mid, b): d
-                for (t, mid, b), d in self.seed_profiles().items()
+        """The profiles measured in this process — store-seeded echoes are
+        excluded, so folding these back into a ProfileStore can never
+        recycle its own estimates."""
+        return {(t, mid, b): d for (t, mid, b), d in self._profiles().items()
                 if (t, b) in self._fresh}
 
     def modeldef(self) -> ModelDef:
-        if not self._measured:
-            self.warmup()
+        """The worker's ground truth: weight bytes and the INFER profile
+        as far as it is known."""
         return ModelDef(model_id=self.model_id,
                         weights_bytes=self.weights_bytes,
                         exec_latency={("INFER", b): d for (_, b), d
@@ -292,10 +286,13 @@ class JaxBackend:
 
     realtime = True
 
-    def __init__(self, models: Dict[str, JaxModel], devices=None):
+    def __init__(self, models: Dict[str, JaxModel], devices):
         self.models = models
-        self.devices = list(devices) if devices is not None \
-            else jax.devices()
+        self.devices = list(devices)
+
+    def describe(self, gpu_id: int) -> dict:
+        d = self.devices[gpu_id]
+        return {"platform": d.platform, "kind": d.device_kind}
 
     def load_duration(self, model: ModelDef, gpu_id: int) -> float:
         return self.models[model.model_id].load(self.devices[gpu_id])
@@ -308,17 +305,17 @@ class JaxBackend:
             action.batch_size, self.devices[action.gpu_id])
 
 
-def seed_engines(engines: Dict[str, JaxModel],
+def seed_engines(engines: Dict[str, JaxModel], device,
                  store: Optional[ProfileStore] = None) -> dict:
     """Seed every engine's profiles — from `store` when it covers the
-    engine's buckets (zero warmup re-measurement), measuring otherwise —
-    and return the combined (type, model, batch) -> secs dict that
-    `Controller.add_worker(profiles=...)` takes."""
+    engine's buckets (zero warmup re-measurement), measuring on `device`
+    otherwise — and return the combined (type, model, batch) -> secs dict
+    that `Controller.add_worker(profiles=...)` takes."""
     profiles = {}
     for e in engines.values():
         if store is not None:
             e.seed_from_store(store)
-        profiles.update(e.seed_profiles())
+        profiles.update(e.seed_profiles(device))
     return profiles
 
 

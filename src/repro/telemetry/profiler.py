@@ -44,27 +44,32 @@ def default_specs(quick: bool = False,
     return specs
 
 
-def profile_engine(jm, reps: int = 3) -> Dict[Tuple[str, str, int], list]:
-    """Measure one JaxModel; returns (action_type, model_id, batch) -> durs."""
+def profile_engine(jm, device, reps: int = 3
+                   ) -> Dict[Tuple[str, str, int], list]:
+    """Measure one JaxModel on `device`; returns (action_type, model_id,
+    batch) -> durs."""
     out = {}
-    for (t, b), durs in jm.measure(reps=reps).items():
+    for (t, b), durs in jm.measure(device, reps=reps).items():
         out[(t, jm.model_id, b)] = durs
-    out[("LOAD", jm.model_id, 1)] = jm.measure_load(reps=max(1, reps - 1))
+    out[("LOAD", jm.model_id, 1)] = jm.measure_load(
+        device, reps=max(1, reps - 1))
     return out
 
 
 def build_store(specs: List[Spec], reps: int = 3,
                 store: Optional[ProfileStore] = None,
                 verbose: bool = False) -> ProfileStore:
+    import jax
+    device = jax.devices()[0]
     store = store if store is not None else ProfileStore()
     for name, mk in specs:
         if verbose:
             print(f"[profiler] compiling + measuring {name} ...",
                   file=sys.stderr)
         jm = mk()
-        for (t, mid, b), durs in profile_engine(jm, reps=reps).items():
+        for (t, mid, b), durs in profile_engine(jm, device,
+                                                reps=reps).items():
             store.update(t, mid, b, durs)
-        jm.unload()
     return store
 
 

@@ -1,7 +1,7 @@
 """The worker's JAX backend on CPU at reduced width: LOAD places a copy's
 weights on the device its gpu_id names, EXEC runs there and never loads,
-UNLOAD frees the buffers, copies share compiled programs, and the daemon
-serves through the TCP runtime."""
+UNLOAD frees the buffers, copies share compiled programs, lanes run on
+threads of their own, and the daemon serves through the TCP runtime."""
 import json
 import os
 import subprocess
@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro.core.actions import Action, ActionType, ResultStatus
-from repro.core.clock import EventLoop, RealClock
+from repro.core.clock import EventLoop, RealClock, RealtimePump
 from repro.core.worker import Worker
 from repro.serving import engine
 from repro.serving.engine import (REDUCED, JaxBackend, NotLoadedError,
@@ -35,12 +35,17 @@ def _env(tmp_path, **extra):
 def _worker(n_models=1, batches=(1,)):
     engines = {f"m{i}": make_resnet_model(f"m{i}", batches=batches, seed=i)
                for i in range(n_models)}
-    loop = EventLoop(RealClock())
-    w = Worker("w0", loop, JaxBackend(engines),
-               resnet_fleet_defs(n_models, REDUCED["scale"]), n_gpus=1)
-    results = []
-    w.on_result = results.append
-    return w, engines, results
+    w, pump, results = _laned(JaxBackend(engines, jax.devices()[:1]), 1,
+                              n_models)
+    return w, pump, engines, results
+
+
+def _serve(w, pump, results, *actions):
+    """Hand `actions` to the worker and pump until each has a result."""
+    n = len(results) + len(actions)
+    for a in actions:
+        w.receive(a)
+    assert pump.run(until=lambda: len(results) == n, timeout=60)
 
 
 def _act(w, kind, mid, gpu=0):
@@ -50,66 +55,112 @@ def _act(w, kind, mid, gpu=0):
 
 
 class _SleepBackend:
-    """Realtime backend whose EXEC takes 20 ms but reports 1 ms."""
+    """Realtime backend whose EXEC takes 20 ms but reports 1 ms; LOAD takes
+    `load_s`. EXEC raises `fail` when one is given."""
     realtime = True
 
+    def __init__(self, load_s=0.0, fail=None):
+        self.load_s, self.fail = load_s, fail
+
     def load_duration(self, model, gpu_id):
+        time.sleep(self.load_s)
         return 1e-3
 
     def unload(self, model, gpu_id):
         pass
 
     def exec_duration(self, model, action):
+        if self.fail is not None:
+            raise self.fail
         time.sleep(0.02)
         return 1e-3
 
 
-def test_realtime_results_time_the_whole_action_and_only_it():
-    """Two devices' EXECs run back to back on the daemon's one thread: the
-    first one's end is stamped when it returned, not after the second ran,
-    and each duration is the wall time it held its executor."""
-    models = resnet_fleet_defs(1, REDUCED["scale"])
-    w = Worker("w0", EventLoop(RealClock()), _SleepBackend(), models,
-               n_gpus=2)
+def _laned(backend, n_gpus, n_models=2):
+    """A worker whose lanes run on threads, driven by a pump."""
+    loop = EventLoop(RealClock())
+    pump = RealtimePump(loop, max_poll=0.002)
+    w = Worker("w0", loop, backend,
+               resnet_fleet_defs(n_models, REDUCED["scale"]), n_gpus=n_gpus,
+               post=pump.post)
     results = []
     w.on_result = results.append
-    for g in (0, 1):
-        w.receive(_act(w, ActionType.LOAD, "m0", g))
-    w.loop.run_all()
-    for g in (0, 1):
-        w.receive(_act(w, ActionType.INFER, "m0", g))
-    w.loop.run_all()
-    first, second = results[2:]
-    assert first.gpu_id == 0 and second.gpu_id == 1
-    for r in (first, second):
+    return w, pump, results
+
+
+def test_lanes_run_on_their_own_threads_and_overlap():
+    """Two devices' EXECs and a LOAD beside one of them run at once, as
+    the controller plans them, each stamped with its own start and end."""
+    w, pump, results = _laned(_SleepBackend(load_s=0.02), n_gpus=2)
+    _serve(w, pump, results,
+           *[_act(w, ActionType.LOAD, "m0", g) for g in (0, 1)])
+    _serve(w, pump, results, _act(w, ActionType.INFER, "m0", 0),
+           _act(w, ActionType.INFER, "m0", 1),
+           _act(w, ActionType.LOAD, "m1", 0))
+    ran = results[2:]
+    assert sorted((r.action_type.value, r.gpu_id) for r in ran) == [
+        ("INFER", 0), ("INFER", 1), ("LOAD", 0)]
+    assert all(r.status is ResultStatus.SUCCESS for r in ran)
+    assert max(r.t_start for r in ran) < min(r.t_end for r in ran)
+    assert all(ex.total_busy > 0 for ex in w.execs.values())
+    w.close()
+    assert not any(ex.lane.is_alive() for ex in w.execs.values())
+
+
+def test_lane_reports_exec_without_weights_and_raises_other_failures():
+    w, pump, results = _laned(_SleepBackend(fail=NotLoadedError("m0")), 1)
+    _serve(w, pump, results, _act(w, ActionType.LOAD, "m0"),
+           _act(w, ActionType.INFER, "m0"))
+    assert {r.action_type: r.status for r in results} == {
+        ActionType.LOAD: ResultStatus.SUCCESS,
+        ActionType.INFER: ResultStatus.ERROR_NOT_LOADED}
+    assert not w.execs[(0, "EXEC")].busy
+    w.backend.fail = ValueError("device lost")
+    w.receive(_act(w, ActionType.INFER, "m0"))
+    with pytest.raises(ValueError, match="device lost"):
+        pump.run(timeout=10)
+
+
+def test_realtime_results_time_the_whole_action_and_only_it():
+    """Each device's EXEC result carries the wall time the action held its
+    executor (20 ms), not the 1 ms the backend reports, and is stamped
+    with its own start and end."""
+    w, pump, results = _laned(_SleepBackend(), n_gpus=2, n_models=1)
+    _serve(w, pump, results,
+           *[_act(w, ActionType.LOAD, "m0", g) for g in (0, 1)])
+    _serve(w, pump, results,
+           *[_act(w, ActionType.INFER, "m0", g) for g in (0, 1)])
+    assert sorted(r.gpu_id for r in results[2:]) == [0, 1]
+    for r in results[2:]:
         assert r.status is ResultStatus.SUCCESS
         assert r.duration >= 0.02
         assert r.t_end - r.t_start == pytest.approx(r.duration)
-    assert first.t_end <= second.t_start
+
+
+def test_realtime_backend_needs_a_way_onto_the_loop_thread():
+    with pytest.raises(ValueError, match="post"):
+        Worker("w0", EventLoop(RealClock()), _SleepBackend(),
+               resnet_fleet_defs(1, REDUCED["scale"]))
 
 
 def test_exec_without_load_is_an_error_result_not_a_silent_load():
-    w, engines, results = _worker()
-    w.receive(_act(w, ActionType.INFER, "m0"))
-    w.loop.run_all()
+    w, pump, engines, results = _worker()
+    _serve(w, pump, results, _act(w, ActionType.INFER, "m0"))
     assert [r.status for r in results] == [ResultStatus.ERROR_NOT_LOADED]
     assert engines["m0"].device_params == {}
     with pytest.raises(NotLoadedError):
-        engines["m0"].run(1)
+        engines["m0"].run(1, jax.devices()[0])
 
 
 def test_unload_frees_the_device_buffers():
-    w, engines, results = _worker()
+    w, pump, engines, results = _worker()
     m = engines["m0"]
-    w.receive(_act(w, ActionType.LOAD, "m0"))
-    w.loop.run_all()
-    w.receive(_act(w, ActionType.INFER, "m0"))
-    w.loop.run_all()
+    _serve(w, pump, results, _act(w, ActionType.LOAD, "m0"))
+    _serve(w, pump, results, _act(w, ActionType.INFER, "m0"))
     assert [r.status for r in results] == [ResultStatus.SUCCESS] * 2
     held = jax.tree.leaves(m.device_params[jax.devices()[0]])
     assert held and not any(a.is_deleted() for a in held)
-    w.receive(_act(w, ActionType.UNLOAD, "m0"))
-    w.loop.run_all()
+    _serve(w, pump, results, _act(w, ActionType.UNLOAD, "m0"))
     assert results[-1].status is ResultStatus.SUCCESS
     assert m.device_params == {}
     assert all(a.is_deleted() for a in held)
@@ -177,15 +228,16 @@ def test_each_gpu_id_places_weights_and_runs_on_its_own_device():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax
         from repro.core.actions import Action, ActionType
-        from repro.core.clock import EventLoop, RealClock
+        from repro.core.clock import EventLoop, RealClock, RealtimePump
         from repro.core.worker import Worker
         from repro.serving.engine import (JaxBackend, resnet_fleet,
                                           resnet_fleet_defs)
         devs = jax.devices()
         engines = resnet_fleet(4, scale=16, img=64)
         loop = EventLoop(RealClock())
+        pump = RealtimePump(loop)
         w = Worker("w0", loop, JaxBackend(engines, devs),
-                   resnet_fleet_defs(4, 16), n_gpus=4)
+                   resnet_fleet_defs(4, 16), n_gpus=4, post=pump.post)
         res = []
         w.on_result = res.append
         for g in range(4):
@@ -194,7 +246,8 @@ def test_each_gpu_id_places_weights_and_runs_on_its_own_device():
                 w.receive(Action(type=kind, model_id=f"m{g}",
                                  worker_id="w0", gpu_id=g, earliest=now,
                                  latest=now + 60, expected_duration=1e-3))
-                loop.run_all()
+                n = len(res) + 1
+                pump.run(until=lambda: len(res) == n, timeout=60)
         where = {mid: [sorted({d.id for a in jax.tree.leaves(p)
                                for d in a.devices()})
                        for p in e.device_params.values()]
@@ -236,7 +289,9 @@ def test_tcp_demo_with_jax_backend_daemon(tmp_path):
     assert out["client"]["goodput"] == out["goodput"]
     assert out["worker_returncodes"] == [0]
     assert out["busy_s"]["w0/gpu0/EXEC"] > 0
+    assert list(out["infer_profile_s"]) == ["1", "2", "4", "8", "16"]
     assert all(v > 0 for v in out["infer_profile_s"].values())
+    assert out["devices"] == {"w0": [{"platform": "cpu", "kind": "cpu"}]}
 
 
 def test_daemon_and_demo_refuse_impossible_device_requests(tmp_path):

@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.clock import EventLoop, RealClock
+from repro.core.clock import EventLoop, RealClock, RealtimePump
 from repro.core.controller import Controller
 from repro.core.scheduler import ClockworkScheduler
 from repro.core.actions import Request
@@ -22,21 +22,23 @@ def test_real_jax_serving_roundtrip():
     go in, on-time responses come out, measured latencies feed the profiler.
     """
     loop = EventLoop(RealClock())
+    pump = RealtimePump(loop)
+    dev = jax.devices()[0]
     jm = make_resnet_model("resnet_tiny", scale=16, batches=(1, 2, 4))
+    profiles = jm.seed_profiles(dev)
     models = {"resnet_tiny": jm.modeldef()}
-    backend = JaxBackend({"resnet_tiny": jm})
-    w = Worker("w0", loop, backend, models, n_gpus=1)
+    backend = JaxBackend({"resnet_tiny": jm}, [dev])
+    w = Worker("w0", loop, backend, models, n_gpus=1, post=pump.post)
     controller = Controller(loop, models, ClockworkScheduler(),
                             action_delay=1e-4)
-    controller.add_worker(w, profiles=jm.seed_profiles())
+    controller.add_worker(w, profiles=profiles)
     done = []
     controller.on_response = done.append
-    t0 = loop.now()
     for i in range(12):
         controller.on_request(Request(model_id="resnet_tiny",
                                       arrival=loop.now(), slo=5.0))
-        loop.run_until(loop.now() + 0.02)
-    loop.run_until(t0 + 20.0 if False else loop.now() + 3.0)
+        pump.run(timeout=0.02)
+    pump.run(timeout=3.0)
     ok = [r for r in done if r.status == "ok"]
     assert len(ok) >= 10, [r.status for r in done]
     # profiler learned real executions

@@ -8,7 +8,8 @@ import math
 import pytest
 
 from repro.core.actions import Action, ActionType, Request
-from repro.core.clock import EventLoop, RealClock, VirtualClock
+from repro.core.clock import (EventLoop, RealClock, RealtimePump,
+                              VirtualClock)
 from repro.core.controller import Controller
 from repro.core.predictor import ActionProfiler
 from repro.core.scheduler import ClockworkScheduler
@@ -244,6 +245,7 @@ def test_successful_result_resets_missed_counter():
 def test_offline_profile_store_enables_zero_warmup_serving(tmp_path):
     """Acceptance: profiler CLI writes a store; a second serving run seeded
     from it performs zero warmup re-measurements and still serves."""
+    import jax
     from repro.serving.engine import (JaxBackend, make_resnet_model,
                                       seed_engines)
     from repro.telemetry import profiler as profcli
@@ -261,23 +263,26 @@ def test_offline_profile_store_enables_zero_warmup_serving(tmp_path):
     store2 = ProfileStore.load(store_path)
     jm = mk()
     assert jm.warmup_count == 0
-    profiles = seed_engines({"rt": jm}, store2)
+    dev = jax.devices()[0]
+    profiles = seed_engines({"rt": jm}, dev, store2)
     models = {"rt": jm.modeldef()}
-    jm.compile()   # AOT compile (untimed) — distinct from re-measurement
+    jm.compile([dev])   # AOT compile (untimed) — not a re-measurement
     assert jm.warmup_count == 0, "modeldef() re-measured despite store"
     assert profiles[("INFER", "rt", 1)] == \
         pytest.approx(store2.get("INFER", "rt", 1).estimate)
 
     loop = EventLoop(RealClock())
-    w = Worker("w0", loop, JaxBackend({"rt": jm}), models, n_gpus=1)
+    pump = RealtimePump(loop)
+    w = Worker("w0", loop, JaxBackend({"rt": jm}, [dev]), models,
+               n_gpus=1, post=pump.post)
     c = Controller(loop, models, ClockworkScheduler(), action_delay=1e-4)
     c.add_worker(w, profiles)
     done = []
     c.on_response = done.append
     for _ in range(4):
         c.on_request(Request(model_id="rt", arrival=loop.now(), slo=10.0))
-        loop.run_until(loop.now() + 0.05)
-    loop.run_until(loop.now() + 3.0)
+        pump.run(timeout=0.05)
+    pump.run(timeout=3.0)
     ok = [r for r in done if r.status == "ok"]
     assert len(ok) >= 3, [r.status for r in done]
     assert jm.warmup_count == 0, "serving run re-measured the model"
@@ -290,6 +295,7 @@ def test_update_store_never_recycles_seeded_estimates(tmp_path):
     """A store covering INFER but missing LOAD forces one load measurement;
     the INFER estimates it seeded must still not be folded back as if they
     were fresh samples."""
+    import jax
     from repro.serving.engine import make_resnet_model, seed_engines, \
         update_store
 
@@ -298,7 +304,7 @@ def test_update_store_never_recycles_seeded_estimates(tmp_path):
     store.update("INFER", "rt", 1, [0.004])   # no ("LOAD", "rt", 1) entry
 
     jm = mk()
-    seed_engines({"rt": jm}, store)
+    seed_engines({"rt": jm}, jax.devices()[0], store)
     assert jm.warmup_count > 0                # it had to measure LOAD
     fresh = jm.fresh_profiles()
     assert ("LOAD", "rt", 1) in fresh
