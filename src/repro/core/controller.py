@@ -15,12 +15,18 @@ Fault tolerance (beyond the paper, §7 "future work"): heartbeats + missing-
 result detection mark workers dead; their mirrors are dropped, outstanding
 requests re-queued, and the LOAD-priority machinery re-replicates their
 models elsewhere. Workers can be added/removed at runtime (elasticity).
+
+Each refusal and each dropped worker carries its cause (REFUSAL_CAUSES,
+FAILURE_CAUSES), counted in `stats`. A refused request's closed span
+records its cause, and each refusal samples the cause's running count as
+the gauge `controller.refused.<cause>`; a dropped worker is logged.
 """
 from __future__ import annotations
 
 import dataclasses
 import heapq
 import itertools
+import logging
 from typing import Callable, Dict, List, Optional
 
 from repro.core.actions import (EXEC_TYPES, Action, ActionType, Request,
@@ -31,6 +37,18 @@ from repro.core.predictor import ActionProfiler
 from repro.core.worker import ModelDef, Worker
 from repro.telemetry.recorder import Recorder
 from repro.telemetry.reports import summarize_run
+
+log = logging.getLogger("repro.core")
+
+# Why a request is refused: `estimate` when its copy's batch-1 estimate
+# alone is longer than the request's SLO, so that no EXEC could have served
+# it; `late` when it waited until too little of its SLO was left.
+REFUSAL_CAUSES = ("estimate", "late")
+# How the failure detector found a worker gone: a heartbeat unanswered
+# within its timeout, `missed_result_threshold` results missing in a row,
+# or the channel to a remote worker closed without a GOODBYE.
+FAILURE_CAUSES = ("heartbeat", "missed_results", "disconnected")
+REFUSED_GAUGE = "controller.refused.{}"
 
 
 @dataclasses.dataclass
@@ -114,6 +132,12 @@ class Controller:
         self.results_log: List[Result] = []
         self.stats = {"goodput": 0, "timeout": 0, "rejected": 0,
                       "cold_starts": 0, "actions": 0, "dead_workers": 0}
+        self.stats.update({f"rejected_{c}": 0 for c in REFUSAL_CAUSES})
+        self.stats.update({f"dead_{c}": 0 for c in FAILURE_CAUSES})
+        # each refusal gauge starts at 0, so a run without refusals reads 0
+        for c in REFUSAL_CAUSES:
+            self.recorder.record_gauge(REFUSED_GAUGE.format(c),
+                                       loop.now(), 0)
 
         scheduler.attach(self)
 
@@ -201,7 +225,12 @@ class Controller:
         self.scheduler.tick()
         self._ensure_ticker()
 
-    def worker_failed(self, worker_id: str):
+    def worker_failed(self, worker_id: str, cause: str):
+        """Drop a worker the failure detector gave up on, for `cause`
+        (one of FAILURE_CAUSES)."""
+        if worker_id in self.workers:
+            self.stats[f"dead_{cause}"] += 1
+            log.warning("worker %s dropped: %s", worker_id, cause)
         self._kill_mirror(worker_id, graceful=False)
 
     def start_heartbeats(self):
@@ -216,7 +245,7 @@ class Controller:
 
                 def check(wid=wid, ok=ok):
                     if not ok["v"]:
-                        self.worker_failed(wid)
+                        self.worker_failed(wid, "heartbeat")
 
                 self.watch_at(self.loop.now() + self.heartbeat_timeout,
                               check)
@@ -278,7 +307,7 @@ class Controller:
                 if mm is not None and aid in mm.outstanding:
                     mm.missed_results += 1
                     if mm.missed_results >= self.missed_result_threshold:
-                        self.worker_failed(wid)
+                        self.worker_failed(wid, "missed_results")
             else:
                 payload()
         if heap:
@@ -312,14 +341,20 @@ class Controller:
         self.scheduler.tick()
         self._ensure_ticker()
 
-    def reject(self, req: Request, when: Optional[float] = None):
+    def reject(self, req: Request, cause: str,
+               when: Optional[float] = None):
+        """Refuse `req` for `cause` (one of REFUSAL_CAUSES)."""
         if req.status is not None:
             return
         req.status = "rejected"
         req.completion = when if when is not None else self.loop.now()
         self.stats["rejected"] += 1
+        key = f"rejected_{cause}"
+        self.stats[key] += 1
+        self.recorder.record_gauge(REFUSED_GAUGE.format(cause),
+                                   req.completion, self.stats[key])
         self.completed.append(req)
-        self.recorder.span_close(req, req.completion)
+        self.recorder.span_close(req, req.completion, cause)
         if self.on_response:
             self.on_response(req)
 

@@ -65,6 +65,13 @@ TICK_LATENCY_GAUGE = "scheduler.tick_latency_s"
 _INF = float("inf")
 
 
+def refusal_cause(req: Request, est1: float) -> str:
+    """Why a hopeless request is refused (controller.REFUSAL_CAUSES):
+    `estimate` when its copy's batch-1 estimate `est1` alone is longer
+    than its SLO, `late` when it waited too long."""
+    return "estimate" if req.deadline - est1 < req.arrival else "late"
+
+
 class ClockworkScheduler:
     def __init__(self, *, schedule_ahead: float = 0.005,
                  batch_sizes=DEFAULT_BATCHES,
@@ -266,7 +273,7 @@ class ClockworkScheduler:
                     continue
                 if r.deadline - est1 < now:
                     self._unqueue_id(r.id)
-                    self.c.reject(r)
+                    self.c.reject(r, refusal_cause(r, est1))
                     changed = True
                     continue
                 if r.deadline < new_min:

@@ -18,6 +18,7 @@ from typing import Deque, Dict, List, Optional, Tuple
 
 from repro.core.actions import (Action, ActionType, Request, Result,
                                 ResultStatus)
+from repro.core.scheduler import refusal_cause
 
 DEFAULT_BATCHES = (1, 2, 4, 8, 16)
 
@@ -101,7 +102,8 @@ class ReferenceClockworkScheduler:
                         changed = True
                         break
                     if r.deadline - self._est_or_scale(mid, 1) < now:
-                        self.c.reject(r)
+                        self.c.reject(r, refusal_cause(
+                            r, self._est_or_scale(mid, 1)))
                         del q[i]
                         changed = True
                         break

@@ -13,7 +13,10 @@ Backends supply durations:
 
 Both answer `load_duration(model, gpu_id)`, `unload(model, gpu_id)`,
 `exec_duration(model, action)` and `describe(gpu_id)` (the device's
-platform and kind, sent in HELLO).
+platform and kind, sent in HELLO). A realtime backend that times the
+phases of its EXEC names them in `exec_phases`; its `exec_duration` then
+takes a third argument, a dict it puts each phase's seconds in, and each
+EXEC executor sums them beside its busy time (`Executor.phase_s`).
 
 A realtime backend's actions take wall time, so a Worker runs each of its
 executors on a thread of its own and hands every end back through `post`,
@@ -123,6 +126,12 @@ class Executor:
         self.busy = False
         self.total_busy = 0.0            # utilization telemetry
         self.lane = _Lane(self) if worker.backend.realtime else None
+        # a realtime EXEC lane's seconds of total_busy in each phase the
+        # backend times, and the rest of its busy time under "other": they
+        # add up to total_busy. None where the backend times no phases.
+        phases = getattr(worker.backend, "exec_phases", ())
+        self.phase_s = dict.fromkeys(phases + ("other",), 0.0) \
+            if phases and name == "EXEC" and self.lane else None
 
     def submit(self, action: Action):
         heapq.heappush(self.q, (action.earliest, next(self._seq), action))
@@ -146,13 +155,14 @@ class Executor:
                 self.worker.emit_result(action, ResultStatus.REJECTED_LATE,
                                         now, now, 0.0)
                 continue
-            status, work = self.worker.perform(action)
+            phases = None if self.phase_s is None else {}
+            status, work = self.worker.perform(action, phases)
             if status is not ResultStatus.SUCCESS:
                 self.worker.emit_result(action, status, now, now, 0.0)
                 continue
             self.busy = True
-            if self.lane is not None:
-                self.lane.jobs.put((action, now, work))   # ends in _end
+            if self.lane is not None:   # ends in _end
+                self.lane.jobs.put((action, now, work, phases))
                 return
             duration = work()
             end = now + duration
@@ -168,12 +178,18 @@ class Executor:
             return
 
     def _end(self, action: Action, t0: float, t1: float,
-             err: Optional[ResultStatus]):
+             err: Optional[ResultStatus], phases: Optional[dict] = None):
         """A realtime action ran from t0 to t1 (called on the loop's
-        thread); its duration is all the time it held this executor."""
+        thread); its duration is all the time it held this executor, of
+        which `phases` holds the parts the backend timed."""
         self.busy = False
         if err is None:
             self.total_busy += t1 - t0
+            if phases is not None:
+                ps = self.phase_s
+                for k, v in phases.items():
+                    ps[k] += v
+                ps["other"] += (t1 - t0) - sum(phases.values())
             self.worker.finish(action)
             self.worker.emit_result(action, ResultStatus.SUCCESS, t0, t1,
                                     t1 - t0)
@@ -201,7 +217,7 @@ class _Lane(threading.Thread):
             job = self.jobs.get()
             if job is None:
                 return
-            action, t0, work = job
+            action, t0, work, phases = job
             err = None
             try:
                 work()
@@ -211,8 +227,8 @@ class _Lane(threading.Thread):
                 post(lambda e=e: _raise(e))
                 return
             t1 = clock.now()    # RealClock reads time.monotonic: any thread
-            post(lambda a=action, t0=t0, t1=t1, e=err:
-                 self.ex._end(a, t0, t1, e))
+            post(lambda a=action, t0=t0, t1=t1, e=err, p=phases:
+                 self.ex._end(a, t0, t1, e, p))
 
 
 def _raise(e: BaseException):
@@ -276,10 +292,11 @@ class Worker:
             lane.join()
 
     # -------------------------------------------------- execution
-    def perform(self, action: Action):
+    def perform(self, action: Action, phases: Optional[dict] = None):
         """Books `action` in the page cache when it starts and returns
         (status, work): `work()` does it on the backend and returns its
-        duration, and is None unless the status is SUCCESS."""
+        duration, and is None unless the status is SUCCESS. An EXEC's
+        timed phases go to `phases` where one is given."""
         pc = self.pagecaches[action.gpu_id]
         model = self.models.get(action.model_id)
         if model is None:
@@ -304,8 +321,9 @@ class Worker:
         if not pc.contains(action.model_id):
             return ResultStatus.ERROR_NOT_LOADED, None
         pc.touch(action.model_id)
+        args = (model, action) if phases is None else (model, action, phases)
         return ResultStatus.SUCCESS, \
-            lambda: self.backend.exec_duration(model, action)
+            lambda: self.backend.exec_duration(*args)
 
     def finish(self, action: Action):
         pass  # hook (real backends release IO buffers here)

@@ -135,7 +135,8 @@ class RemoteWorkerStub:
         was_alive = self.alive
         self.alive = False
         if was_alive and not self.graceful:
-            self.server.controller.worker_failed(self.worker_id)
+            self.server.controller.worker_failed(self.worker_id,
+                                                 "disconnected")
 
 
 class ControllerServer:
@@ -277,7 +278,9 @@ class ControllerServer:
     # --------------------------------------------------------- lifecycle
     def shutdown(self) -> None:
         """Graceful stop: tell every live daemon to wind down (they flush
-        telemetry and exit), then stop accepting."""
+        telemetry and exit) and retire its mirror, as a daemon's own
+        GOODBYE does, so no detector counts a worker told to leave as
+        failed; then stop accepting."""
         if self.closed:
             return
         self.closed = True
@@ -285,6 +288,7 @@ class ControllerServer:
             if stub.alive:
                 stub.graceful = True
                 stub.channel.send(protocol.goodbye("controller shutdown"))
+                self.controller.remove_worker(stub.worker_id)
         if self._tcp is not None:
             # keep live channels open: daemons flush telemetry, ack, and
             # hang up themselves; we only stop accepting new ones

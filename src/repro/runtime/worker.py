@@ -13,11 +13,11 @@ SimBackend or the real JAX engine runners) and bridges it over a Channel:
   worker-side stamps on one consistent clock;
 * PING is answered like the in-process `Worker.ping` (after
   `result_delay`, only while alive), so heartbeat semantics match;
-* worker-side telemetry (per-executor busy-seconds and queue depth, clock
-  offset) is sampled periodically into a buffer and flushed as TELEMETRY
-  frames when the buffer fills — and always on `shutdown()`, so a
-  daemon's final samples are never lost (`telemetry_report` counts match
-  single-process runs).
+* worker-side telemetry (per-executor busy-seconds, and an EXEC lane's
+  seconds in each phase its backend times) is sampled periodically into a
+  buffer and flushed as TELEMETRY frames when the buffer fills — and
+  always on `shutdown()`, so a daemon's final samples are never lost
+  (`telemetry_report` counts match single-process runs).
 
 `python -m repro.runtime.worker --controller HOST:PORT ...` runs the
 daemon: a RealClock EventLoop + RealtimePump, a Worker, and a TCP channel
@@ -179,17 +179,20 @@ class WorkerHost:
         self.loop.schedule_in(self.telemetry_interval, self._telemetry_tick)
 
     def sample_telemetry(self) -> None:
-        """Append one round of worker-side gauges (controller timeline)."""
+        """Append one round of worker-side gauges (controller timeline):
+        each lane's cumulative `busy_s`, and beside an EXEC lane's the
+        seconds of it in each phase (`input_s`, `dispatch_s`, `wait_s`,
+        `other_s` under the JAX backend), which add up to it."""
         now_r = self.sync.to_remote(self.loop.now())
         wid = self.worker.worker_id
         add = self._pending.append
         for (g, lane), ex in self.worker.execs.items():
-            add(GaugeSample(name=f"worker/{wid}/gpu{g}/{lane}/busy_s",
-                            t=now_r, value=ex.total_busy))
-            add(GaugeSample(name=f"worker/{wid}/gpu{g}/{lane}/queue_depth",
-                            t=now_r, value=float(len(ex.q))))
-        add(GaugeSample(name=f"worker/{wid}/clock_offset_s", t=now_r,
-                        value=self.sync.offset))
+            prefix = f"worker/{wid}/gpu{g}/{lane}"
+            add(GaugeSample(name=f"{prefix}/busy_s", t=now_r,
+                            value=ex.total_busy))
+            for phase, secs in (ex.phase_s or {}).items():
+                add(GaugeSample(name=f"{prefix}/{phase}_s", t=now_r,
+                                value=secs))
 
     def flush_telemetry(self, sample_first: bool = False) -> None:
         """Ship buffered gauges. Called when the buffer fills and — the

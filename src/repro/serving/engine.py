@@ -22,6 +22,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.worker import ModelDef, NotLoadedError
@@ -41,6 +42,11 @@ REDUCED = {"scale": 16, "img": 64}
 # 8-bit float (roundoff 2^-4) would miss it.
 LOGITS_RTOL = 0.02
 CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+# The host phases of one EXEC, in order (JaxModel.run): `input` copies the
+# host input to the device (the host transposes it into the device's
+# layout there), `dispatch` calls the compiled program, `wait` blocks until
+# its output is ready. Each is a profiler span "exec/<phase>".
+EXEC_PHASES = ("input", "dispatch", "wait")
 
 
 def use_compile_cache() -> None:
@@ -136,24 +142,45 @@ class JaxModel:
         return self.batches[-1]
 
     def execute(self, b: int, x, device):
-        """Run bucket `b` on input `x` with the weights held on `device`;
-        returns the (not yet awaited) output. Never loads weights."""
+        """Run bucket `b` on input `x`, on the host or already on `device`,
+        with the weights held on `device`; returns the (not yet awaited)
+        output. Never loads weights."""
         params = self.device_params.get(device)
         if params is None:
             raise NotLoadedError(
                 f"{self.model_id} has no weights on {device}")
         exe = self.programs.get(device, b, self.host_params, x)
-        return exe(params, jax.device_put(x, device))
+        if not isinstance(x, jax.Array):
+            x = jax.device_put(x, device)
+        return exe(params, x)
 
-    def run(self, batch: int, device) -> float:
+    def run(self, batch: int, device, phases: Optional[dict] = None,
+            gpu: Optional[int] = None) -> float:
         """One EXEC: copy a `batch`-bucket input to `device` and run the
         program there. Returns its wall time, which is all the work the
-        action does, so the profile it feeds covers the whole action."""
+        action does, so the profile it feeds covers the whole action.
+        Each phase (EXEC_PHASES) is a profiler span that names the lane's
+        `gpu` (default: the device's id), the copy and the bucket; its
+        seconds go into `phases` where one is given."""
         b = self.bucket(batch)
         x = self._input(b)
+        args = {"gpu": device.id if gpu is None else gpu,
+                "copy": self.model_id, "bucket": b}
         t0 = time.perf_counter()
-        jax.block_until_ready(self.execute(b, x, device))
-        return time.perf_counter() - t0
+        with TraceAnnotation("exec/input", **args):
+            x = jax.device_put(x, device)
+        t1 = time.perf_counter()
+        with TraceAnnotation("exec/dispatch", **args):
+            out = self.execute(b, x, device)
+        t2 = time.perf_counter()
+        with TraceAnnotation("exec/wait", **args):
+            jax.block_until_ready(out)
+        t3 = time.perf_counter()
+        if phases is not None:
+            phases["input"] = t1 - t0
+            phases["dispatch"] = t2 - t1
+            phases["wait"] = t3 - t2
+        return t3 - t0
 
     def _input(self, b: int):
         """The host input of bucket `b`: one seeded payload per bucket
@@ -285,6 +312,7 @@ class JaxBackend:
     is `devices[g]`."""
 
     realtime = True
+    exec_phases = EXEC_PHASES
 
     def __init__(self, models: Dict[str, JaxModel], devices):
         self.models = models
@@ -300,9 +328,11 @@ class JaxBackend:
     def unload(self, model: ModelDef, gpu_id: int) -> None:
         self.models[model.model_id].unload(self.devices[gpu_id])
 
-    def exec_duration(self, model: ModelDef, action) -> float:
+    def exec_duration(self, model: ModelDef, action,
+                      phases: Optional[dict] = None) -> float:
         return self.models[model.model_id].run(
-            action.batch_size, self.devices[action.gpu_id])
+            action.batch_size, self.devices[action.gpu_id], phases,
+            gpu=action.gpu_id)
 
 
 def seed_engines(engines: Dict[str, JaxModel], device,

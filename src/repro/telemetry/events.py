@@ -46,6 +46,7 @@ class RequestSpan:
     exec_end: float = NAN
     response: float = NAN      # completion/rejection time
     status: Optional[str] = None   # "ok" | "timeout" | "rejected"
+    cause: Optional[str] = None    # why it was rejected (Controller.reject)
     worker_id: Optional[str] = None
     gpu_id: int = -1
     batch_size: int = 0

@@ -104,7 +104,7 @@ class Recorder:
                 s.load_end = t_end
                 s.cold_start = True
 
-    def span_close(self, req, when: float):
+    def span_close(self, req, when: float, cause: Optional[str] = None):
         s = self._open.pop(req.id, None)
         if s is None:
             return None
@@ -115,6 +115,7 @@ class Recorder:
                 del self._open_by_model[s.model_id]
         s.response = when
         s.status = req.status
+        s.cause = cause
         if len(self.spans) == self.capacity:
             self.dropped_spans += 1
         self.spans.append(s)

@@ -12,6 +12,8 @@ from repro.core.scheduler import ClockworkScheduler
 from repro.core.worker import ModelDef, SimBackend, Worker
 from repro.serving.simulator import build_cluster, table1_modeldef
 from repro.serving.workload import ClosedLoopClient, OpenLoopClient
+from repro.telemetry import prediction_error_report
+from repro.telemetry.events import ActionRecord
 
 
 # ------------------------------------------------------------- PageCache
@@ -57,15 +59,26 @@ def test_profiler_rolling_max_prediction():
     p = ActionProfiler(window=5)
     p.seed("INFER", "m", 1, 0.010)
     assert p.estimate("INFER", "m", 1) == pytest.approx(0.010)
-    for d in (0.002, 0.003, 0.001):
+    records = []
+
+    def observe(d):
+        # as the controller records each result: the estimate beside the
+        # measured duration
+        records.append(ActionRecord(
+            len(records), "INFER", "m", "w0", 0, 1, "SUCCESS", 0.0, 0.0,
+            d, d, predicted=p.estimate("INFER", "m", 1)))
         p.observe("INFER", "m", 1, d)
+
+    for d in (0.002, 0.003, 0.001):
+        observe(d)
     assert p.estimate("INFER", "m", 1) == pytest.approx(0.003)
     # window slides: old max falls out
     for d in (0.001,) * 5:
-        p.observe("INFER", "m", 1, d)
+        observe(d)
     assert p.estimate("INFER", "m", 1) == pytest.approx(0.001)
-    # over/under errors recorded
-    assert len(p.over_errors) + len(p.under_errors) == 8
+    # every prediction's error is in the records
+    rep = prediction_error_report(records)
+    assert rep["over"]["n"] + rep["under"]["n"] == 8
 
 
 # ------------------------------------------------------------- worker

@@ -13,9 +13,14 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core.actions import Action, ActionType, ResultStatus
+from repro.core.actions import Action, ActionType, Request, ResultStatus
 from repro.core.clock import EventLoop, RealClock, RealtimePump
+from repro.core.controller import Controller
+from repro.core.scheduler import ClockworkScheduler
 from repro.core.worker import Worker
+from repro.runtime.controller import ControllerServer
+from repro.runtime.transport import LoopbackLink
+from repro.runtime.worker import WorkerHost
 from repro.serving import engine
 from repro.serving.engine import (REDUCED, JaxBackend, NotLoadedError,
                                   check_logits, make_resnet_model,
@@ -135,6 +140,73 @@ def test_realtime_results_time_the_whole_action_and_only_it():
         assert r.status is ResultStatus.SUCCESS
         assert r.duration >= 0.02
         assert r.t_end - r.t_start == pytest.approx(r.duration)
+
+
+def test_exec_phases_add_up_to_the_lanes_busy_time_at_the_controller():
+    """A reduced-width copy served by the controller through a realtime
+    lane and a loopback channel: the EXEC lane's input, dispatch, wait and
+    other seconds add up to its busy time, and reach the controller's
+    Recorder as gauges beside `busy_s`. The LOAD lane times no phases."""
+    engines = {"m0": make_resnet_model("m0", batches=(1, 2), seed=0)}
+    loop = EventLoop(RealClock())
+    pump = RealtimePump(loop, max_poll=0.002)
+    w = Worker("w0", loop, JaxBackend(engines, jax.devices()[:1]),
+               resnet_fleet_defs(1, REDUCED["scale"]), post=pump.post)
+    controller = Controller(loop, resnet_fleet_defs(1, REDUCED["scale"]),
+                            ClockworkScheduler(), default_slo=30.0)
+    server = ControllerServer(controller, estimate_net_delay=False)
+    link = LoopbackLink(loop)
+    server.adopt(link.a)
+    host = WorkerHost(w, link.b, telemetry_interval=None)
+    host.register()
+    for _ in range(6):
+        controller.on_request(Request(model_id="m0", arrival=loop.now(),
+                                      slo=30.0))
+    assert pump.run(until=lambda: len(controller.completed) == 6,
+                    timeout=120)
+    assert {r.status for r in controller.completed} == {"ok"}
+    host.flush_telemetry(sample_first=True)
+    w.close()
+
+    ex = w.execs[(0, "EXEC")]
+    assert set(ex.phase_s) == {"input", "dispatch", "wait", "other"}
+    assert all(ex.phase_s[p] > 0 for p in engine.EXEC_PHASES)
+    assert ex.phase_s["other"] >= 0
+    assert sum(ex.phase_s.values()) == pytest.approx(ex.total_busy,
+                                                     abs=1e-9)
+    assert w.execs[(0, "LOAD")].phase_s is None
+    rec = controller.recorder
+    got = {g.name: g.value for g in rec.iter_gauges()
+           if g.name.startswith("worker/")}
+    lane = "worker/w0/gpu0/EXEC"
+    assert got[f"{lane}/busy_s"] == ex.total_busy
+    assert {p: got[f"{lane}/{p}_s"] for p in ex.phase_s} == ex.phase_s
+    assert sorted(n for n in got if "/LOAD/" in n) == [
+        "worker/w0/gpu0/LOAD/busy_s"]
+
+
+def test_exec_phases_are_profiler_spans_naming_their_lane(tmp_path):
+    """Each phase of an EXEC is a span "exec/<phase>" in the profiler's
+    trace, in order, carrying the lane's gpu, the copy and the bucket."""
+    from jax.profiler import ProfileData
+    m = make_resnet_model("m3", batches=(1, 2), seed=0)
+    dev = jax.devices()[0]
+    m.load(dev)
+    m.run(2, dev)                # compiled and warm before the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        m.run(2, dev, gpu=5)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    spans = sorted((e.start_ns, e.name, dict(e.stats))
+                   for plane in ProfileData.from_file(str(path)).planes
+                   for line in plane.lines for e in line.events
+                   if e.name.startswith("exec/"))
+    assert [n for _, n, _ in spans] == [
+        f"exec/{p}" for p in engine.EXEC_PHASES]
+    assert all(a == {"gpu": 5, "copy": "m3", "bucket": 2}
+               for _, _, a in spans)
 
 
 def test_realtime_backend_needs_a_way_onto_the_loop_thread():

@@ -111,8 +111,8 @@ def test_prediction_errors_are_small():
     client = ClosedLoopClient(cl.loop, cl.submit, "m0", 0.100, concurrency=8)
     cl.attach_clients([client])
     cl.run(5.0)
-    prof = cl.controller.profiler
-    errs = sorted(prof.over_errors + prof.under_errors)
+    errs = sorted(abs(a.error) for a in cl.controller.recorder.iter_actions()
+                  if a.status == "SUCCESS" and a.predicted is not None)
     assert errs, "no predictions recorded"
     p99 = errs[int(0.99 * (len(errs) - 1))]
     assert p99 < 0.002  # paper Fig 9: ~250us at v100 scale

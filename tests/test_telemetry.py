@@ -18,6 +18,7 @@ from repro.serving.simulator import build_cluster, table1_modeldef
 from repro.serving.workload import ClosedLoopClient
 from repro.telemetry import (LatencyProfile, ProfileStore, Recorder,
                              latency_breakdown, prediction_error_report)
+from repro.telemetry.events import ActionRecord
 
 
 # ---------------------------------------------------------- ActionProfiler
@@ -44,13 +45,25 @@ def test_profiler_seed_fallback_until_first_observation():
 
 
 def test_profiler_over_under_error_accounting():
+    """Each result's record holds the profiler's estimate beside the
+    measured duration; prediction_error_report splits their errors into
+    over- and under-predictions."""
     p = ActionProfiler()
     p.seed("INFER", "m", 1, 0.010)
-    p.observe("INFER", "m", 1, 0.004)   # pred 0.010 -> over by 0.006
-    p.observe("INFER", "m", 1, 0.003)   # pred 0.004 -> over by 0.001
-    p.observe("INFER", "m", 1, 0.009)   # pred 0.004 -> under by 0.005
-    assert p.over_errors == pytest.approx([0.006, 0.001])
-    assert p.under_errors == pytest.approx([0.005])
+    records = []
+    for d in (0.004,    # pred 0.010 -> over by 0.006
+              0.003,    # pred 0.004 -> over by 0.001
+              0.009):   # pred 0.004 -> under by 0.005
+        records.append(ActionRecord(
+            len(records), "INFER", "m", "w0", 0, 1, "SUCCESS", 0.0, 0.0,
+            d, d, predicted=p.estimate("INFER", "m", 1)))
+        p.observe("INFER", "m", 1, d)
+    assert [a.error for a in records] == pytest.approx(
+        [0.006, 0.001, -0.005])
+    rep = prediction_error_report(records)
+    assert (rep["over"]["n"], rep["under"]["n"]) == (2, 1)
+    assert rep["over"]["max_us"] == pytest.approx(6000)
+    assert rep["under"]["max_us"] == pytest.approx(5000)
 
 
 def test_profiler_history_snapshot():
