@@ -16,7 +16,10 @@ Both answer `load_duration(model, gpu_id)`, `unload(model, gpu_id)`,
 platform and kind, sent in HELLO). A realtime backend that times the
 phases of its EXEC names them in `exec_phases`; its `exec_duration` then
 takes a third argument, a dict it puts each phase's seconds in, and each
-EXEC executor sums them beside its busy time (`Executor.phase_s`).
+EXEC executor sums them beside its busy time (`Executor.phase_s`). The
+dict's `slab_n`, where the backend sets it, is 1 for an EXEC whose input
+crossed to the device as a lane-dense slab; the executor counts those
+(`Executor.slab_n`).
 
 A realtime backend's actions take wall time, so a Worker runs each of its
 executors on a thread of its own and hands every end back through `post`,
@@ -132,6 +135,8 @@ class Executor:
         phases = getattr(worker.backend, "exec_phases", ())
         self.phase_s = dict.fromkeys(phases + ("other",), 0.0) \
             if phases and name == "EXEC" and self.lane else None
+        # and its count of EXECs whose input crossed as a slab
+        self.slab_n = None if self.phase_s is None else 0
 
     def submit(self, action: Action):
         heapq.heappush(self.q, (action.earliest, next(self._seq), action))
@@ -186,6 +191,7 @@ class Executor:
         if err is None:
             self.total_busy += t1 - t0
             if phases is not None:
+                self.slab_n += phases.pop("slab_n", 0)
                 ps = self.phase_s
                 for k, v in phases.items():
                     ps[k] += v

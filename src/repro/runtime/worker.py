@@ -182,7 +182,8 @@ class WorkerHost:
         """Append one round of worker-side gauges (controller timeline):
         each lane's cumulative `busy_s`, and beside an EXEC lane's the
         seconds of it in each phase (`input_s`, `dispatch_s`, `wait_s`,
-        `other_s` under the JAX backend), which add up to it."""
+        `other_s` under the JAX backend), which add up to it, and its count
+        of EXECs whose input crossed as a slab (`slab_n`)."""
         now_r = self.sync.to_remote(self.loop.now())
         wid = self.worker.worker_id
         add = self._pending.append
@@ -193,6 +194,9 @@ class WorkerHost:
             for phase, secs in (ex.phase_s or {}).items():
                 add(GaugeSample(name=f"{prefix}/{phase}_s", t=now_r,
                                 value=secs))
+            if ex.slab_n is not None:
+                add(GaugeSample(name=f"{prefix}/slab_n", t=now_r,
+                                value=ex.slab_n))
 
     def flush_telemetry(self, sample_first: bool = False) -> None:
         """Ship buffered gauges. Called when the buffer fills and — the

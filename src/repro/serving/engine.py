@@ -15,12 +15,15 @@ warmup re-measurements (`warmup_count` stays 0).
 """
 from __future__ import annotations
 
+import functools
+import math
 import os
 import time
 from pathlib import Path
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 from jax.sharding import SingleDeviceSharding
@@ -43,10 +46,13 @@ REDUCED = {"scale": 16, "img": 64}
 LOGITS_RTOL = 0.02
 CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 # The host phases of one EXEC, in order (JaxModel.run): `input` copies the
-# host input to the device (the host transposes it into the device's
-# layout there), `dispatch` calls the compiled program, `wait` blocks until
-# its output is ready. Each is a profiler span "exec/<phase>".
+# host input to the device, as a lane-dense slab where the input allows
+# (`slab_shape`), `dispatch` calls the compiled program, `wait` blocks until
+# its output is ready. Each is a profiler span "exec/<phase>"; the input's
+# also names its `layout`, "slab" or "as-is".
 EXEC_PHASES = ("input", "dispatch", "wait")
+# The minor dimension of a TPU memory tile (8 x 128 for 32-bit elements)
+LANES = 128
 
 
 def use_compile_cache() -> None:
@@ -69,6 +75,46 @@ def device_memory_bytes(device) -> int:
     raise RuntimeError(f"{device} reports no memory limit")
 
 
+def slab_shape(x) -> Optional[Tuple[int, ...]]:
+    """The lane-dense shape (batch, rows, LANES) in which the host input `x`
+    (an array or a ShapeDtypeStruct) crosses to the device, or None where it
+    crosses as it is. A single floating-point array whose per-example
+    element count is a multiple of LANES crosses as a slab: the same C-order
+    bytes, which for float32 with rows a multiple of 8 are already the TPU's
+    tiled layout. An image batch with 3 channels minor is not, and the
+    runtime would rearrange it on the host before its copy could start.
+    The batch stays a dimension of its own: folded into the rows, it made
+    the v5e compiler take minutes over full-width ResNet-50's bucket 8."""
+    shape = getattr(x, "shape", None)
+    if (shape is None or len(shape) < 2
+            or not jnp.issubdtype(x.dtype, jnp.floating)
+            or math.prod(shape[1:]) % LANES):
+        return None
+    return shape[0], math.prod(shape[1:]) // LANES, LANES
+
+
+def to_wire(x):
+    """The input `x` as it crosses to the device: a reshape of it into its
+    slab (a view, for an array), or `x` itself where it crosses as it is or
+    is a slab already (`slab_shape`)."""
+    shape = slab_shape(x)
+    if shape is None or shape == tuple(x.shape):
+        return x
+    if isinstance(x, jax.ShapeDtypeStruct):
+        return jax.ShapeDtypeStruct(shape, x.dtype)
+    return x.reshape(shape)
+
+
+def _from_slab(forward: Callable, shape: tuple) -> Callable:
+    """`forward` taking its input as the slab of an array of `shape`, which
+    it reshapes back on the device. It keeps `forward`'s name, and so the
+    compiled program keeps its module name (jit_<name>)."""
+    @functools.wraps(forward)
+    def on_slab(params, slab):
+        return forward(params, slab.reshape(shape))
+    return on_slab
+
+
 class Executables:
     """One compiled executable per (device, batch bucket) of a forward
     function. Copies of a model differ only in their weights, so they share
@@ -81,8 +127,8 @@ class Executables:
 
     def get(self, device, b: int, params, x):
         """The executable for bucket `b` on `device`, compiled on first use
-        for arguments shaped like `params` and `x` (arrays or
-        ShapeDtypeStructs)."""
+        for arguments shaped like `params` and the host input `x` (arrays or
+        ShapeDtypeStructs). It takes the input as `to_wire(x)`."""
         exe = self._exe.get((device, b))
         if exe is None:
             on_device = SingleDeviceSharding(device)
@@ -91,9 +137,12 @@ class Executables:
                 return jax.ShapeDtypeStruct(a.shape, a.dtype,
                                             sharding=on_device)
 
+            forward = self.forward if slab_shape(x) is None \
+                else _from_slab(self.forward, tuple(x.shape))
             t0 = time.perf_counter()
-            exe = jax.jit(self.forward).lower(
-                jax.tree.map(spec, params), jax.tree.map(spec, x)).compile()
+            exe = jax.jit(forward).lower(
+                jax.tree.map(spec, params),
+                jax.tree.map(spec, to_wire(x))).compile()
             self.compile_s[(device, b)] = time.perf_counter() - t0
             self._exe[(device, b)] = exe
         return exe
@@ -142,14 +191,16 @@ class JaxModel:
         return self.batches[-1]
 
     def execute(self, b: int, x, device):
-        """Run bucket `b` on input `x`, on the host or already on `device`,
-        with the weights held on `device`; returns the (not yet awaited)
-        output. Never loads weights."""
+        """Run bucket `b` on input `x`, shaped like the bucket's input
+        (`_input`) or already as it crosses (`to_wire`), on the host or
+        already on `device`, with the weights held on `device`; returns the
+        (not yet awaited) output. Never loads weights."""
         params = self.device_params.get(device)
         if params is None:
             raise NotLoadedError(
                 f"{self.model_id} has no weights on {device}")
-        exe = self.programs.get(device, b, self.host_params, x)
+        exe = self.programs.get(device, b, self.host_params, self._input(b))
+        x = to_wire(x)
         if not isinstance(x, jax.Array):
             x = jax.device_put(x, device)
         return exe(params, x)
@@ -161,14 +212,17 @@ class JaxModel:
         action does, so the profile it feeds covers the whole action.
         Each phase (EXEC_PHASES) is a profiler span that names the lane's
         `gpu` (default: the device's id), the copy and the bucket; its
-        seconds go into `phases` where one is given."""
+        seconds go into `phases` where one is given, and `slab_n` there is
+        1 where the input crossed as a slab."""
         b = self.bucket(batch)
         x = self._input(b)
+        wire = to_wire(x)
+        layout = "as-is" if wire is x else "slab"
         args = {"gpu": device.id if gpu is None else gpu,
                 "copy": self.model_id, "bucket": b}
         t0 = time.perf_counter()
-        with TraceAnnotation("exec/input", **args):
-            x = jax.device_put(x, device)
+        with TraceAnnotation("exec/input", layout=layout, **args):
+            x = jax.device_put(wire, device)
         t1 = time.perf_counter()
         with TraceAnnotation("exec/dispatch", **args):
             out = self.execute(b, x, device)
@@ -180,11 +234,12 @@ class JaxModel:
             phases["input"] = t1 - t0
             phases["dispatch"] = t2 - t1
             phases["wait"] = t3 - t2
+            phases["slab_n"] = int(layout == "slab")
         return t3 - t0
 
     def _input(self, b: int):
-        """The host input of bucket `b`: one seeded payload per bucket
-        stands in for the requests' own."""
+        """The host input of bucket `b`, as the model takes it: one seeded
+        payload per bucket stands in for the requests' own."""
         x = self._inputs.get(b)
         if x is None:
             x = self._inputs[b] = self.make_input(b)

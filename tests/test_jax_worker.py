@@ -205,8 +205,9 @@ def test_exec_phases_are_profiler_spans_naming_their_lane(tmp_path):
                    if e.name.startswith("exec/"))
     assert [n for _, n, _ in spans] == [
         f"exec/{p}" for p in engine.EXEC_PHASES]
-    assert all(a == {"gpu": 5, "copy": "m3", "bucket": 2}
-               for _, _, a in spans)
+    lane = {"gpu": 5, "copy": "m3", "bucket": 2}
+    assert [a for _, _, a in spans] == [{**lane, "layout": "slab"}, lane,
+                                        lane]
 
 
 def test_realtime_backend_needs_a_way_onto_the_loop_thread():

@@ -42,7 +42,7 @@ def no_persistent_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("batch", [1, 16])
+@pytest.mark.parametrize("batch", [1, 8, 16])
 def test_resnet50_bucket_compiles_for_v5e(v5e_chip, no_persistent_cache,
                                           batch):
     spec = resnet50_spec(num_classes=1000, scale=PUBLISHED["scale"])
