@@ -2,11 +2,12 @@
 
     python bench/control.py --config rn50-x15-1chip --seeds 1 2 3
 
-For each seed, every copy's every batch bucket is computed by the float8
-forward put in the program's place (reference.py, `control=True`) and
-judged as a run judges the daemon's outputs. Every reading has to be far
-above run.LOGIT_GAP_LIMIT. The benchmark's own runs never run this; the
-readings and the limit set from them are in PERF.md.
+For each seed, the control of the model that the configuration names
+(its module's `control_gap`: the reference, in a precision below the
+served one, put in the program's place for every copy's every batch
+bucket) is judged as a run judges the daemon's outputs. Every reading has
+to be far above the model's `GAP_LIMIT`. The benchmark's own runs never
+run this; the readings and the limit set from them are in PERF.md.
 """
 from __future__ import annotations
 
@@ -26,24 +27,23 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
     sys.path.insert(0, str(BENCH))
     import jax
-    import reference
-    from run import LOGIT_GAP_LIMIT
-    cfg = json.loads((BENCH / "configs" / f"{args.config}.json").read_text())
+    from run import load_config, load_model
+    cfg = load_config(args.config)
+    model = load_model(cfg)
+    size = model.size(cfg, False)
     dev = jax.devices()[0]
     print(f"[control] {dev.platform} {dev.device_kind}", file=sys.stderr)
     readings = {}
     for seed in args.seeds:
         t0 = time.monotonic()
-        readings[seed] = reference.control_gap(
-            seed, cfg["copies"], scale=cfg["width_divisor"],
-            img=cfg["image_size"], num_classes=cfg["num_classes"])
+        readings[seed] = model.control_gap(seed, cfg["copies"], size)
         print(f"[control] seed {seed}: logit_gap {readings[seed]!r} limit "
-              f"{LOGIT_GAP_LIMIT!r} ({time.monotonic() - t0:.1f} s)",
+              f"{model.GAP_LIMIT!r} ({time.monotonic() - t0:.1f} s)",
               file=sys.stderr, flush=True)
     print(json.dumps({"platform": dev.platform, "kind": dev.device_kind,
                       "config": args.config, "control_gap": readings,
-                      "limit": LOGIT_GAP_LIMIT}))
-    return 0 if min(readings.values()) > LOGIT_GAP_LIMIT else 1
+                      "limit": model.GAP_LIMIT}))
+    return 0 if min(readings.values()) > model.GAP_LIMIT else 1
 
 
 if __name__ == "__main__":

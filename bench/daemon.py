@@ -1,7 +1,8 @@
 """The program's worker daemon, run for one benchmark run.
 
-    python bench/daemon.py --out FILE [--trace-dir DIR --trace-at S
-        --trace-s S] -- <arguments of python -m repro.runtime.worker>
+    python bench/daemon.py --out FILE --config NAME [--rehearsal]
+        [--trace-dir DIR --trace-at S --trace-s S]
+        -- <arguments of python -m repro.runtime.worker>
 
 Runs `repro.runtime.worker.main` unchanged, the served path itself, and
 around it what only the process that holds the chips can do:
@@ -15,8 +16,10 @@ around it what only the process that holds the chips can do:
   `--trace-at` seconds after the window opened.
 * When the daemon has left, it reads the chips' peak memory, frees the
   program's state, reduces the trace (xplane.py) and compares the kept
-  outputs with the plain reference (reference.py), and writes all of it
-  as one JSON object to `--out`.
+  outputs with the plain reference of the model that configuration
+  `--config` names (its module's `compare`, at the sizes the served path
+  runs, the rehearsal's with `--rehearsal`), and writes all of it as one
+  JSON object to `--out`.
 """
 from __future__ import annotations
 
@@ -36,11 +39,11 @@ def parse(argv):
     p.add_argument("--trace-dir", default=None)
     p.add_argument("--trace-at", type=float, default=0.0)
     p.add_argument("--trace-s", type=float, default=0.0)
-    p.add_argument("--scale", type=int, default=1,
-                   help="width divisor of the served copies, for the "
-                        "reference")
-    p.add_argument("--img", type=int, default=224,
-                   help="image side of the served copies' inputs")
+    p.add_argument("--config", required=True,
+                   help="the configuration served, as BENCHMARK.json names "
+                        "it")
+    p.add_argument("--rehearsal", action="store_true",
+                   help="the worker serves the model's rehearsal size")
     own, worker_argv = p.parse_args(argv[:argv.index("--")]), \
         argv[argv.index("--") + 1:]
     w = argparse.ArgumentParser(add_help=False)
@@ -102,8 +105,8 @@ class Tracer:
     def _run(self) -> None:
         import jax
         opts = jax.profiler.ProfileOptions()
-        # Python frames, and the runtime's verbose host events (a million
-        # input-transpose chunks a second), would swamp the traced host
+        # Python frames, and the runtime's verbose host events, would
+        # swamp the traced host
         opts.python_tracer_level = 0
         opts.host_tracer_level = 1
         try:
@@ -124,6 +127,9 @@ class Tracer:
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else argv
     own, served, worker_argv = parse(argv)
+    from run import load_config, load_model
+    config = load_config(own.config)
+    model = load_model(config)
     capture = Capture()
     tracer = Tracer(own.trace_dir, own.trace_at, own.trace_s) \
         if own.trace_dir else None
@@ -162,10 +168,9 @@ def main(argv=None) -> int:
             out["trace"].update(xplane.reduce(
                 xplane.load(files[0]), tracer.t_off - tracer.t_on))
 
-    import reference
     t0 = time.monotonic()
-    out["check"] = reference.compare(outputs, served.seed, scale=own.scale,
-                                     img=own.img)
+    out["check"] = model.compare(outputs, served.seed,
+                                 model.size(config, own.rehearsal))
     out["check"]["buckets"] = sorted({b for _, b in outputs})
     out["check"]["reference_s"] = time.monotonic() - t0
     with open(own.out, "w") as f:

@@ -1,8 +1,8 @@
 """Worker daemon: share of each device's EXEC lane busy time spent in the
-`input` phase, copying the host input to the device (the host transposes
-it into the device's layout there): the rise of the daemon's cumulative
-`input_s` gauge over the rise of its `busy_s` gauge across the window,
-mean over devices. A daemon that times no phases reports none."""
+`input` phase, copying the host input to the device: the rise of the
+daemon's cumulative `input_s` gauge over the rise of its `busy_s` gauge
+across the window, mean over devices. A daemon that times no phases
+reports none."""
 
 
 def read(run):

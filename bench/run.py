@@ -6,7 +6,11 @@
 The cell (BENCHMARK.json `workloads`) names a configuration, whose file
 gives the deployment (model, copies, SLO, controller settings), and a
 traffic mix, found as bench/traffic/<traffic>.json, which gives the
-arrivals, the popularity over the copies and the total rate. The served path runs as users see it:
+arrivals, the popularity over the copies and the total rate. The
+configuration's required key `model` names its module,
+bench/models/<model>.py: what belongs to one model (sizes, the worker's
+arguments, the plain reference and its limit, operations and bytes) comes
+from there and from nowhere else. The served path runs as users see it:
 
 * this process runs the program's controller (`Controller` with the
   `ClockworkScheduler` behind a `ControllerServer` on localhost TCP), as
@@ -33,8 +37,8 @@ another model. A request that the controller refused, or answered after
 its SLO, was answered: it is an SLO miss, counted against `goodput_rps`
 and printed on stderr, not a failure. A run whose daemon finds no TPU, or fewer chips
 than the cell asks for, prints no result and exits 1. `--rehearsal` runs
-everything at 1/16 width on whatever JAX finds, for a CPU, and prints no
-result either.
+everything at the model's rehearsal size, small enough for a CPU, on
+whatever JAX finds, and prints no result either.
 """
 from __future__ import annotations
 
@@ -48,6 +52,7 @@ import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
+import re  # noqa: E402
 import shutil  # noqa: E402
 import signal  # noqa: E402
 import subprocess  # noqa: E402
@@ -57,21 +62,42 @@ from pathlib import Path  # noqa: E402
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
-REHEARSAL_SIZE = {"scale": 16, "img": 64}   # the daemon's --reduced copies
 LEAD_S = 2.0                  # generators start and connect in this time
 MAX_RATE_PER_GENERATOR = 1000.0
 REGISTER_S = 1100.0           # a first run compiles every program
 ANSWER_WAIT_S = 60.0          # matches loadgen.ANSWER_WAIT_S
 DAEMON_EXIT_S = 240.0         # the daemon's reference check runs in this
 TRACE_S = 3.0                 # length of the traced part of the window
-# Widest gap by which a served row of logits may lie from the float32
-# reference's, relative to the reference's norm (reference.row_gaps); set
-# from the sound runs' readings and the float8 control's, see PERF.md.
-LOGIT_GAP_LIMIT = 0.02
 
 
 class RunError(RuntimeError):
     """The run could not be measured: no result is printed."""
+
+
+def load_config(name: str) -> dict:
+    """The file of configuration `name`, as BENCHMARK.json lists it."""
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    entry = {c["name"]: c for c in spec["configs"]}[name]
+    return json.loads((ROOT / entry["file"]).read_text())
+
+
+def load_model(config: dict):
+    """The module bench/models/<model>.py of the model that `config`
+    names under its required key `model`."""
+    model = config.get("model")
+    if not (isinstance(model, str)
+            and re.fullmatch(r"[A-Za-z0-9_]+", model)):
+        raise RunError(f"configuration {config.get('name')!r} names no "
+                       f"model: its key \"model\" is {model!r}")
+    path = BENCH / "models" / f"{model}.py"
+    if not path.is_file():
+        raise RunError(f"configuration {config.get('name')!r} names model "
+                       f"{model!r}, and there is no {path.relative_to(ROOT)}")
+    spec = importlib.util.spec_from_file_location(f"model_{model}", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod        # as an import would: dataclasses
+    spec.loader.exec_module(mod)        # look their module up there
+    return mod
 
 
 def load_cell(name: str):
@@ -80,8 +106,8 @@ def load_cell(name: str):
     if name not in cells:
         raise RunError(f"no cell {name!r}; cells: {sorted(cells)}")
     cell = cells[name]
-    entry = {c["name"]: c for c in spec["configs"]}[cell["config"]]
-    config = json.loads((ROOT / entry["file"]).read_text())
+    config = load_config(cell["config"])
+    model = load_model(config)
     traffic = json.loads(
         (BENCH / "traffic" / f"{cell['traffic']}.json").read_text())
     e2e = [m for m in spec["end_to_end"]
@@ -90,7 +116,7 @@ def load_cell(name: str):
     per_layer = [m for m in spec["per_layer"]
                  if name in m.get("workloads", [name])
                  and m["moves"] in reported]
-    return cell, config, traffic, e2e, per_layer
+    return cell, config, model, traffic, e2e, per_layer
 
 
 def reader(metric: str):
@@ -192,25 +218,23 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     docstring) with the run's diagnostics under "diag"."""
     sys.path[:0] = [str(ROOT / "src"), str(BENCH)]
     import arrivals
-    import counts
     from peaks import peaks
-    from record import Run, bucket, quantile
+    from record import Run, quantile
     from repro.core.clock import EventLoop, RealClock, RealtimePump
     from repro.core.controller import Controller
     from repro.core.scheduler import ClockworkScheduler
     from repro.core.worker import ModelDef
     from repro.runtime.controller import ControllerServer
 
-    cell, config, traffic, e2e, per_layer = load_cell(cell_name)
-    size = dict(REHEARSAL_SIZE) if rehearsal else {
-        "scale": config["width_divisor"], "img": config["image_size"]}
-    size["num_classes"] = config["num_classes"]
-    weights_bytes = 2 * counts.param_count(size["scale"], size["num_classes"])
-    # what the daemon serves, which the configuration has to state as it is
-    served = {"dtype": "bfloat16", "clients": "open-loop",
-              "batch_buckets": list(counts.BUCKETS)}
-    if not rehearsal:
-        served["weights_bytes_per_copy"] = weights_bytes
+    cell, config, model, traffic, e2e, per_layer = load_cell(cell_name)
+    size = model.size(config, rehearsal)
+    # what the daemon serves, which the configuration has to state as it
+    # is; a rehearsal's copies are smaller than the configuration's
+    served = model.served(size)
+    weights_bytes = served["weights_bytes_per_copy"]
+    if rehearsal:
+        del served["weights_bytes_per_copy"]
+    served["clients"] = "open-loop"
     for key, value in served.items():
         if config[key] != value:
             raise RunError(f"the configuration states {key} {config[key]!r};"
@@ -255,7 +279,9 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         port = server.listen_tcp("127.0.0.1", 0, pump.post)
         cmd = [sys.executable, str(BENCH / "daemon.py"),
                "--out", str(tmp / "daemon.json"),
-               "--scale", str(size["scale"]), "--img", str(size["img"])]
+               "--config", cell["config"]]
+        if rehearsal:
+            cmd.append("--rehearsal")
         if trace:
             cmd += ["--trace-dir", str(tmp / "trace"),
                     "--trace-at", str(max(0.0, (seconds - TRACE_S) / 2)),
@@ -263,8 +289,7 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
         cmd += ["--", "--controller", f"127.0.0.1:{port}", "--worker-id",
                 "w0", "--backend", "jax", "--n-models", str(copies),
                 "--gpus", str(chips), "--seed", str(seed)]
-        if rehearsal:
-            cmd.append("--reduced")
+        cmd += model.worker_args(size, rehearsal)
         daemon = subprocess.Popen(cmd, env=env, stdout=sys.stderr)
         procs.append(daemon)
         pump.run(until=lambda: "w0" in controller.workers
@@ -286,10 +311,10 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
             raise RunError(f"the daemon drives {len(devices)} devices, the "
                            f"cell asks for {chips}")
         profiled = {(mid, b) for t, mid, b in hello_profiles if t == "INFER"}
-        if profiled != {(m, b) for m in models for b in counts.BUCKETS}:
+        if profiled != {(m, b) for m in models for b in model.BUCKETS}:
             raise RunError(f"the daemon profiled (copy, batch) "
                            f"{sorted(profiled)}, not every copy at buckets "
-                           f"{counts.BUCKETS}")
+                           f"{model.BUCKETS}")
         peak = None if rehearsal and platform != "tpu" else peaks(kind)
         controller.start_heartbeats()
 
@@ -353,7 +378,8 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     if trace and (tr is None or "busy_s" not in tr):
         raise RunError(f"no trace was read: {tr}")
     record = Run(seconds=seconds, chips=chips, window=window, client=client,
-                 actions=actions, gauges=gauges, size=size, peak=peak,
+                 actions=actions, gauges=gauges, model=model, size=size,
+                 peak=peak,
                  trace=tr, trace_window=None if tr is None else
                  (tr["t_on"] - base, tr["t_off"] - base))
 
@@ -368,12 +394,12 @@ def run(cell_name: str, seed: int, seconds: float, trace: bool, *,
     metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
                for m in metrics_def if values.get(m["name"]) is not None}
 
-    ran = {bucket(a.batch_size) for a in record.execs()}
+    ran = {record.bucket(a.batch_size) for a in record.execs()}
     chk = out["check"]
     checks = {
         "rows_compared": {"value": chk["rows"], "min": 1},
         "logit_gap": {"value": chk["logit_gap_max"],
-                      "limit": LOGIT_GAP_LIMIT},
+                      "limit": model.GAP_LIMIT},
         "unchecked_buckets": {"value": len(ran - set(chk["buckets"])),
                               "limit": 0},
         "unanswered": {"value": client["unanswered"], "limit": 0},
@@ -450,7 +476,8 @@ def main(argv=None) -> int:
     p.add_argument("--seconds", type=float, required=True)
     p.add_argument("--trace", type=int, choices=(0, 1), default=0)
     p.add_argument("--rehearsal", action="store_true",
-                   help="1/16 width on any device; prints no result")
+                   help="the model's rehearsal size on any device; prints "
+                        "no result")
     p.add_argument("--rate-rps", type=float, default=None,
                    help="offer this total rate instead of the cell's (the "
                         "knee sweep)")

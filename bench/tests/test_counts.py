@@ -1,6 +1,6 @@
 import pytest
 
-import counts
+from models import resnet50 as counts
 from peaks import peaks
 
 

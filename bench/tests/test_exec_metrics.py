@@ -13,7 +13,7 @@ LANE = "worker/w0/gpu{}/EXEC"
 def _run(gauges, attempted=100, chips=1):
     return Run(seconds=10.0, chips=chips, window=(5.0, 15.0),
                client={"attempted": attempted}, actions=[], gauges=gauges,
-               size={}, peak=None)
+               model=None, size={}, peak=None)
 
 
 def test_exec_input_share_is_its_rise_over_the_busy_rise():
